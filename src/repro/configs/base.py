@@ -142,7 +142,8 @@ class ModelConfig:
     attention_impl: str = "naive"
     ssm_impl: str = "xla"
     # linear-scan backend for recurrent mixers (minGRU/Mamba prefill):
-    #   seq | xla | pallas (interpret) | pallas_tpu (compiled)
+    #   seq | xla | pallas (compiled on TPU, interpreted elsewhere) |
+    #   pallas_tpu (compiled unconditionally)
     scan_backend: str = "xla"
     # paged-KV decode attention read (serving, kv_layout="paged"):
     #   pallas     — kernels.paged_attention block-table kernel, platform-

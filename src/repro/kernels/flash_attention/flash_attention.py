@@ -32,7 +32,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -131,7 +130,7 @@ def flash_fwd(q, k, v, *, bq=128, bk=128, causal=True, window=None,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -260,7 +259,7 @@ def flash_bwd(q, k, v, o, lse, do, *, bq=128, bk=128, causal=True,
         ],
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                         pltpu.VMEM((bk, D), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -283,7 +282,7 @@ def flash_bwd(q, k, v, o, lse, do, *, bq=128, bk=128, causal=True,
                                lambda b, h, qi, ki: (b, h, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, S, D), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -1,8 +1,9 @@
 """Public flash-attention op with custom VJP + analytic roofline cost model.
 
 ``flash_attention(q, k, v, causal, window, backend)``:
-  * backend "pallas"      — the TPU kernel in interpret mode (CPU tests)
-  * backend "pallas_tpu"  — compiled (production)
+  * backend "pallas"      — the TPU kernel: compiled on TPU, interpreted
+                            elsewhere (CPU tests)
+  * backend "pallas_tpu"  — compiled unconditionally
   * backend "xla"         — naive reference (baseline path)
 
 The VJP runs the FlashAttention-2 backward kernels (dKdV + dQ), reducing
@@ -17,6 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.dispatch import interpret
 from repro.kernels.flash_attention import ref
 from repro.kernels.flash_attention.flash_attention import flash_bwd, flash_fwd
 
@@ -45,7 +47,7 @@ def _fwd(q, k, v, causal, window, backend):
     group = q.shape[1] // k.shape[1]
     b = _blocks(q.shape[2])
     out, lse = flash_fwd(q, k, v, bq=b, bk=b, causal=causal, window=window,
-                         group=group, interpret=(backend == "pallas"))
+                         group=group, interpret=interpret(backend))
     return out, (q, k, v, out, lse)
 
 
@@ -64,7 +66,7 @@ def _bwd(causal, window, backend, res, g):
     b = _blocks(q.shape[2])
     dq, dk, dv = flash_bwd(q, k, v, out, lse, g, bq=b, bk=b, causal=causal,
                            window=window, group=group,
-                           interpret=(backend == "pallas"))
+                           interpret=interpret(backend))
     B, H, S, D = q.shape
     KV = k.shape[1]
     dk = dk.reshape(B, KV, H // KV, S, D).sum(2).astype(k.dtype)

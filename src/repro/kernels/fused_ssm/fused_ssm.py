@@ -27,8 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 
 def _fwd_kernel(dt_ref, x_ref, b_ref, c_ref, a_ref, y_ref, hout_ref,
                 h_s, *, tblk):
@@ -136,7 +134,7 @@ def fused_ssm_fwd(dt, x, Bm, Cm, A, *, tblk=64, dblk=128, interpret=True):
             jax.ShapeDtypeStruct((B, n_t, di, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((dblk, n), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="fused_ssm_fwd",
@@ -189,7 +187,7 @@ def fused_ssm_bwd(dt, x, Bm, Cm, A, h_entries, dy, *, tblk=64, dblk=128,
             pltpu.VMEM((tblk, dblk, n), jnp.float32),    # local trajectory
             pltpu.VMEM((dblk, n), jnp.float32),          # dA accumulator
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="fused_ssm_bwd",

@@ -6,6 +6,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.dispatch import interpret
 from repro.kernels.fused_ssm import ref
 from repro.kernels.fused_ssm.fused_ssm import fused_ssm_bwd, fused_ssm_fwd
 
@@ -31,7 +32,7 @@ def _fwd(dt, x, Bm, Cm, A, backend):
     tblk = _blk(x.shape[1], (256, 128, 64, 32, 16, 8, 4, 2, 1))
     dblk = _blk(x.shape[2], (128, 64, 32, 16, 8, 4, 2, 1))
     y, h_entries = fused_ssm_fwd(dt, x, Bm, Cm, A, tblk=tblk, dblk=dblk,
-                                 interpret=(backend == "pallas"))
+                                 interpret=interpret(backend))
     return y, (dt, x, Bm, Cm, A, h_entries)
 
 
@@ -45,7 +46,7 @@ def _bwd(backend, res, dy):
     dblk = _blk(x.shape[2], (128, 64, 32, 16, 8, 4, 2, 1))
     ddt, dx, dBp, dCp, dAp = fused_ssm_bwd(
         dt, x, Bm, Cm, A, h_entries, dy, tblk=tblk, dblk=dblk,
-        interpret=(backend == "pallas"))
+        interpret=interpret(backend))
     B, T, di = x.shape
     n_d = di // dblk
     dB = dBp.reshape(B, n_d, T, -1).sum(1).astype(Bm.dtype)

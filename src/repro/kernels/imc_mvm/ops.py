@@ -11,6 +11,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.dispatch import interpret
 from repro.kernels.imc_mvm import ref
 from repro.kernels.imc_mvm.imc_mvm import imc_mvm_pallas
 
@@ -46,7 +47,7 @@ def imc_mvm(x, codes, scale, *, backend=_DEFAULT_BACKEND,
         cp = jnp.pad(codes.astype(jnp.int8), [(0, Kp - K), (0, Np - N)])
         sp = jnp.pad(scale, [(0, Np - N)])
         out = imc_mvm_pallas(xp, cp, sp, bm=bm_, bn=bn_, bk=bk_,
-                             interpret=(backend == "pallas"))
+                             interpret=interpret(backend))
         # kernel divides by padded K; rescale to true K
         out = out[:M, :N] * (Kp / K)
     else:

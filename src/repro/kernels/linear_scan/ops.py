@@ -17,13 +17,14 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.dispatch import interpret
 from repro.kernels.linear_scan import ref
 from repro.kernels.linear_scan.linear_scan import linear_scan_pallas
 
 # Backend selection:
 #   "xla"       — associative scan (O(log T) depth); default on CPU hosts
-#   "pallas"    — the TPU kernel in interpret mode (CPU validation)
-#   "pallas_tpu"— the TPU kernel, compiled (production)
+#   "pallas"    — the TPU kernel: compiled on TPU, interpreted elsewhere
+#   "pallas_tpu"— the TPU kernel, compiled unconditionally
 #   "seq"       — definitional lax.scan (debugging)
 _DEFAULT_BACKEND = "xla"
 
@@ -47,7 +48,7 @@ def _dispatch(a, b, h0, backend, tblk, dblk):
         bp = jnp.pad(b, pad3)
         h0p = jnp.pad(h0, [(0, 0), (0, Dp - D)])
         h = linear_scan_pallas(ap, bp, h0p, tblk=tblk, dblk=dblk,
-                               interpret=(backend == "pallas"))
+                               interpret=interpret(backend))
         return h[:, :T, :D]
     raise ValueError(f"unknown backend {backend!r}")
 

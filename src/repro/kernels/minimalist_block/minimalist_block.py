@@ -27,39 +27,45 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
 
 GATE_UNITS = 63.0
 
 
 def _kernel(x_ref, ch_ref, cz_ref, bh_ref, bz_ref, h0_ref, y_ref, h_ref,
-            h_s, *, tblk, scale):
+            h_s, pre_h_s, zq_s, *, tblk, scale):
     ti = pl.program_id(2)
 
     @pl.when(ti == 0)
     def _():
-        h_s[...] = h0_ref[...].astype(jnp.float32)
+        h_s[...] = h0_ref[0].astype(jnp.float32)
 
     x = x_ref[0].astype(jnp.float32)                       # (tblk, K)
     wh = (ch_ref[...].astype(jnp.float32) - 1.5) * scale   # (K, nblk)
     wz = (cz_ref[...].astype(jnp.float32) - 1.5) * scale
-    pre_h = jax.lax.dot_general(x, wh, (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32) \
-        + bh_ref[...].astype(jnp.float32)
+    pre_h_s[...] = jax.lax.dot_general(
+        x, wh, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32) + bh_ref[...].astype(jnp.float32)
     pre_z = jax.lax.dot_general(x, wz, (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32) \
         + bz_ref[...].astype(jnp.float32)
     # SAR-ADC transfer (mid-rise floor on the 63-unit capacitor grid)
-    zq = jnp.floor(jnp.clip(pre_z / 6.0 + 0.5, 0.0, 1.0) * GATE_UNITS) \
-        / GATE_UNITS
+    zq_s[...] = jnp.floor(jnp.clip(pre_z / 6.0 + 0.5, 0.0, 1.0)
+                          * GATE_UNITS) / GATE_UNITS
 
-    def step(t, h):
-        h = zq[t] * pre_h[t] + (1.0 - zq[t]) * h
-        h_ref[0, t, :] = h.astype(h_ref.dtype)
-        y_ref[0, t, :] = (h > 0.0).astype(y_ref.dtype)
+    # the time loop indexes rows of fp32 VMEM refs (Mosaic lowers a
+    # dynamic row index on an fp32 ref, not a dynamic slice of a value);
+    # each step's h overwrites the pre_h row it was computed from, and
+    # the chunk's outputs leave in two whole-block stores
+    def step(t, h):                                        # h: (1, nblk)
+        row = pl.ds(t, 1)
+        zq = zq_s[row, :]
+        h = zq * pre_h_s[row, :] + (1.0 - zq) * h
+        pre_h_s[row, :] = h
         return h
 
-    h_s[0] = jax.lax.fori_loop(0, tblk, step, h_s[0])
+    h_s[...] = jax.lax.fori_loop(0, tblk, step, h_s[...])
+    h_ref[0] = pre_h_s[...].astype(h_ref.dtype)
+    y_ref[0] = (pre_h_s[...] > 0.0).astype(y_ref.dtype)
 
 
 def _step_kernel(x_ref, ch_ref, cz_ref, bh_ref, bz_ref, h0_ref, y_ref, h_ref,
@@ -113,7 +119,7 @@ def minimalist_step_pallas(x, codes_h, codes_z, scale, bh, bz, h_prev, *,
             jax.ShapeDtypeStruct((B, N), x.dtype),
             jax.ShapeDtypeStruct((B, N), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
         name="minimalist_step",
@@ -140,7 +146,9 @@ def minimalist_block_pallas(x, codes_h, codes_z, scale, bh, bz, h0, *,
             pl.BlockSpec((K, nblk), lambda b, n, t: (0, n)),
             pl.BlockSpec((1, nblk), lambda b, n, t: (0, n)),
             pl.BlockSpec((1, nblk), lambda b, n, t: (0, n)),
-            pl.BlockSpec((1, nblk), lambda b, n, t: (b, n)),
+            # h0 as (B, 1, N): a (1, nblk) tile of (B, N) breaks the TPU's
+            # (8, 128) rule for the second-to-last block dim
+            pl.BlockSpec((1, 1, nblk), lambda b, n, t: (b, 0, n)),
         ],
         out_specs=[
             pl.BlockSpec((1, tblk, nblk), lambda b, n, t: (b, t, n)),
@@ -150,9 +158,11 @@ def minimalist_block_pallas(x, codes_h, codes_z, scale, bh, bz, h0, *,
             jax.ShapeDtypeStruct((B, T, N), x.dtype),
             jax.ShapeDtypeStruct((B, T, N), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((1, nblk), jnp.float32)],
-        compiler_params=CompilerParams(
+        scratch_shapes=[pltpu.VMEM((1, nblk), jnp.float32),
+                        pltpu.VMEM((tblk, nblk), jnp.float32),
+                        pltpu.VMEM((tblk, nblk), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="minimalist_block",
-    )(x, codes_h, codes_z, bh.reshape(1, N), bz.reshape(1, N), h0)
+    )(x, codes_h, codes_z, bh.reshape(1, N), bz.reshape(1, N), h0[:, None, :])

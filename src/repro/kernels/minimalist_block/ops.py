@@ -10,6 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import quant
+from repro.kernels.dispatch import interpret
 from repro.kernels.minimalist_block import ref
 from repro.kernels.minimalist_block.minimalist_block import (
     minimalist_block_pallas, minimalist_step_pallas)
@@ -19,12 +20,19 @@ def _pad_to(v, m):
     return (v + m - 1) // m * m
 
 
-def _largest_divisor(n, ladder=(128, 64, 32, 16, 8, 4, 2, 1)):
-    """Biggest tile in the ladder dividing n (1 always does)."""
-    for cand in ladder:
+def _row_tile(n):
+    """Time tile: the biggest multiple of 8 up to 128 dividing n, else all
+    of n (the TPU takes a second-to-last block dim that is a multiple of
+    8 or the whole axis)."""
+    for cand in (128, 64, 32, 16, 8):
         if n % cand == 0:
             return cand
     return n
+
+
+def _lane_tile(n):
+    """Channel tile: 128 lanes where they divide n, else all of n."""
+    return 128 if n % 128 == 0 else n
 
 
 def from_block_params(params):
@@ -52,13 +60,13 @@ def minimalist_block(x, codes_h, codes_z, scale, bh, bz, h0=None, *,
         return ref.minimalist_block_ref(x, jnp.asarray(codes_h),
                                         jnp.asarray(codes_z), scale,
                                         jnp.asarray(bh), jnp.asarray(bz), h0)
-    tblk = _largest_divisor(T)
-    nblk = _largest_divisor(N)
+    tblk = _row_tile(T)
+    nblk = _lane_tile(N)
     y, h = minimalist_block_pallas(
         x, jnp.asarray(codes_h, jnp.int8), jnp.asarray(codes_z, jnp.int8),
         float(scale), jnp.asarray(bh, jnp.float32),
         jnp.asarray(bz, jnp.float32), h0, tblk=tblk, nblk=nblk,
-        interpret=(backend == "pallas"))
+        interpret=interpret(backend))
     return y, h
 
 
@@ -73,12 +81,12 @@ def minimalist_step(x, codes_h, codes_z, scale, bh, bz, h_prev, *,
                                        jnp.asarray(codes_z), scale,
                                        jnp.asarray(bh), jnp.asarray(bz),
                                        h_prev)
-    nblk = _largest_divisor(N)
+    nblk = _lane_tile(N)
     return minimalist_step_pallas(
         x, jnp.asarray(codes_h, jnp.int8), jnp.asarray(codes_z, jnp.int8),
         float(scale), jnp.asarray(bh, jnp.float32),
         jnp.asarray(bz, jnp.float32), h_prev, nblk=nblk,
-        interpret=(backend == "pallas"))
+        interpret=interpret(backend))
 
 
 def cost_model(B, T, K, N, *, dtype_bytes=2):

@@ -28,8 +28,7 @@ occupancy), plus q/out, which is the whole point.
 """
 from __future__ import annotations
 
-import jax
-
+from repro.kernels.dispatch import interpret
 from repro.kernels.paged_attention import ref
 from repro.kernels.paged_attention.paged_attention import (
     paged_gqa_fwd, paged_gqa_fwd_q8, paged_mla_fwd, paged_mla_fwd_q8)
@@ -41,11 +40,6 @@ def _check_backend(backend):
     if backend not in BACKENDS:
         raise ValueError(f"backend must be one of {BACKENDS}, "
                          f"got {backend!r}")
-
-
-def _interpret(backend):
-    # "pallas" = fast path everywhere: interpret off-TPU, compiled on TPU
-    return backend == "pallas" and jax.default_backend() != "tpu"
 
 
 def _check_scales(a, b, names):
@@ -76,10 +70,10 @@ def paged_gqa_attention(q, pool_k, pool_v, block_tables, pos, *, length,
         return paged_gqa_fwd_q8(q, pool_k, pool_v, k_scale, v_scale,
                                 block_tables, pos, length=length,
                                 window=window,
-                                interpret=_interpret(backend))
+                                interpret=interpret(backend))
     return paged_gqa_fwd(q, pool_k, pool_v, block_tables, pos,
                          length=length, window=window,
-                         interpret=_interpret(backend))
+                         interpret=interpret(backend))
 
 
 def paged_mla_attention(q_abs, q_rope, pool_ckv, pool_krope, block_tables,
@@ -99,10 +93,10 @@ def paged_mla_attention(q_abs, q_rope, pool_ckv, pool_krope, block_tables,
         return paged_mla_fwd_q8(q_abs, q_rope, pool_ckv, pool_krope,
                                 ckv_scale, krope_scale, block_tables, pos,
                                 length=length, scale=scale,
-                                interpret=_interpret(backend))
+                                interpret=interpret(backend))
     return paged_mla_fwd(q_abs, q_rope, pool_ckv, pool_krope, block_tables,
                          pos, length=length, scale=scale,
-                         interpret=_interpret(backend))
+                         interpret=interpret(backend))
 
 
 def cost_model(B, H, KV, hd, *, live_tokens, page_size, dtype_bytes=2,

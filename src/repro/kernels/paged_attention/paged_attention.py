@@ -11,9 +11,11 @@ the pages a request owns, one page per innermost grid step, with the
 online-softmax state (m, l, acc) resident in VMEM.  Nothing dense is ever
 materialized; HBM traffic is the live pages + q/out.
 
-Grid (GQA): (B, KV, n_pages) with the page axis innermost and sequential;
-each step loads pool block ``block_tables[b, p]`` for kv head ``kv``.
-Masking reconstructs the absolute position of every in-page entry:
+Grid (GQA): (B, n_pages) with the page axis innermost and sequential;
+each step loads pool block ``block_tables[b, p]`` whole — every KV head of
+the page, since the TPU only takes a one-head block when KV divides into
+sublane tiles — and loops over the heads in-register.  Masking
+reconstructs the absolute position of every in-page entry:
 
   * global:       k_pos = j            (in-cache index == position)
   * window ring:  k_pos = pos - ((pos - j) % length)   [length <= window]
@@ -26,11 +28,11 @@ schedule over latent pages with a rank-space score sum
 
 The ``_q8`` variants read int8 pools with per-page float32 scales
 (GQA: one per page per KV head; MLA: one per page — see
-``paged_attention.quant``).  The scale rides in as a (1, 1) block
-through the same block-table index map as the page it describes and the
-dequant (codes * scale) happens in-register right before the q·Kᵀ and
-P·V dots — HBM streams half the KV bytes and nothing dequantized is
-ever written back.
+``paged_attention.quant``).  The scales ride in as a (1, 1, KV) or
+(1, 1, 1) block through the same block-table index map as the page they
+describe, and the dequant (codes * scale) happens in-register right
+before the q·Kᵀ and P·V dots — HBM streams half the KV bytes and nothing
+dequantized is ever written back.
 """
 from __future__ import annotations
 
@@ -41,7 +43,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -72,9 +73,18 @@ def _online_update(s, v, acc, m_s, l_s):
     m_s[...] = m_new
 
 
-def _gqa_kernel(pos_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
-                acc, m_s, l_s, *, ps, n_pages, length, window, scale):
-    b, p = pl.program_id(0), pl.program_id(2)
+def _gqa_kernel(pos_ref, bt_ref, q_ref, k_ref, v_ref, *refs, ps, n_pages,
+                length, window, scale):
+    """One (request, page) grid cell over every KV head of the page.
+
+    The page block is (1, ps, KV, hd): its last two dims are whole axes,
+    which the TPU accepts for any KV and hd, where a one-head block
+    (1, ps, 1, hd) is refused unless KV divides into sublane tiles.
+    ``refs`` is (out, acc, m, l), led by the (1, 1, KV) k/v scale blocks
+    for int8 pools."""
+    *scales, o_ref, acc, m_s, l_s = refs
+    b, p = pl.program_id(0), pl.program_id(1)
+    n_kv = k_ref.shape[2]
 
     @pl.when(p == 0)
     def _():
@@ -88,18 +98,65 @@ def _gqa_kernel(pos_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
     # every j <= pos), so the gate only drops unwritten chain tails
     @pl.when((p * ps <= pos) & (p * ps < length))
     def _():
-        q = q_ref[0, 0].astype(jnp.float32)        # (G, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # (ps, hd)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        s = s + _page_mask(pos, p, ps, length, window)
-        _online_update(s, v_ref[0, :, 0, :].astype(jnp.float32),
-                       acc, m_s, l_s)
+        mask = _page_mask(pos, p, ps, length, window)
+        for kv in range(n_kv):
+            q = q_ref[0, kv].astype(jnp.float32)        # (G, hd)
+            k = k_ref[0, :, kv, :].astype(jnp.float32)  # (ps, hd)
+            v = v_ref[0, :, kv, :].astype(jnp.float32)
+            if scales:
+                k = k * scales[0][0, :, kv:kv + 1]
+                v = v * scales[1][0, :, kv:kv + 1]
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            _online_update(s * scale + mask, v, acc.at[kv], m_s.at[kv],
+                           l_s.at[kv])
 
     @pl.when(p == n_pages - 1)
     def _():
-        o_ref[0, 0] = (acc[...] / jnp.maximum(l_s[...], 1e-30)
-                       ).astype(o_ref.dtype)
+        o_ref[0] = (acc[...] / jnp.maximum(l_s[...], 1e-30)
+                    ).astype(o_ref.dtype)
+
+
+def _gqa_call(q, pool_k, pool_v, scales, block_tables, pos, *, length,
+              window, interpret):
+    """The GQA pallas_call; ``scales`` is () for bf16 pools or the
+    (k_scale, v_scale) pair, each (P, KV) float32, for int8 pools."""
+    B, H, hd = q.shape
+    P, ps, KV, _ = pool_k.shape
+    G = H // KV
+    n_pages = -(-length // ps)
+    bt = block_tables[:, :n_pages].astype(jnp.int32)
+    qg = q.reshape(B, KV, G, hd)
+    kern = functools.partial(_gqa_kernel, ps=ps, n_pages=n_pages,
+                             length=length, window=window,
+                             scale=1.0 / (hd ** 0.5))
+    page_map = lambda b, p, pos_ref, bt_ref: (bt_ref[b, p], 0, 0, 0)
+    sc_map = lambda b, p, pos_ref, bt_ref: (bt_ref[b, p], 0, 0)
+    q_map = lambda b, p, pos_ref, bt_ref: (b, 0, 0, 0)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, n_pages),
+        in_specs=[pl.BlockSpec((1, KV, G, hd), q_map),
+                  pl.BlockSpec((1, ps, KV, hd), page_map),
+                  pl.BlockSpec((1, ps, KV, hd), page_map)]
+                 + [pl.BlockSpec((1, 1, KV), sc_map)] * len(scales),
+        out_specs=pl.BlockSpec((1, KV, G, hd), q_map),
+        scratch_shapes=[
+            pltpu.VMEM((KV, G, hd), jnp.float32),
+            pltpu.VMEM((KV, G, 1), jnp.float32),
+            pltpu.VMEM((KV, G, 1), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        kern, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="paged_gqa_decode_q8" if scales else "paged_gqa_decode",
+    )(pos.astype(jnp.int32), bt, qg, pool_k, pool_v,
+      *(sc.astype(jnp.float32).reshape(P, 1, KV) for sc in scales))
+    return out.reshape(B, H, hd)
 
 
 @functools.partial(jax.jit,
@@ -108,70 +165,8 @@ def paged_gqa_fwd(q, pool_k, pool_v, block_tables, pos, *, length,
                   window=None, interpret=True):
     """q: (B, H, hd); pool_k/v: (P, page, KV, hd); block_tables:
     (B, >=ceil(length/page)) int32; pos: (B,) int32 -> (B, H, hd)."""
-    B, H, hd = q.shape
-    _P, ps, KV, _ = pool_k.shape
-    G = H // KV
-    n_pages = -(-length // ps)
-    bt = block_tables[:, :n_pages].astype(jnp.int32)
-    qg = q.reshape(B, KV, G, hd)
-    kern = functools.partial(_gqa_kernel, ps=ps, n_pages=n_pages,
-                             length=length, window=window,
-                             scale=1.0 / (hd ** 0.5))
-    kv_map = lambda b, kv, p, pos_ref, bt_ref: (bt_ref[b, p], 0, kv, 0)
-    q_map = lambda b, kv, p, pos_ref, bt_ref: (b, kv, 0, 0)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, KV, n_pages),
-        in_specs=[
-            pl.BlockSpec((1, 1, G, hd), q_map),
-            pl.BlockSpec((1, ps, 1, hd), kv_map),
-            pl.BlockSpec((1, ps, 1, hd), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, hd), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((G, hd), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kern, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-        name="paged_gqa_decode",
-    )(pos.astype(jnp.int32), bt, qg, pool_k, pool_v)
-    return out.reshape(B, H, hd)
-
-
-def _gqa_kernel_q8(pos_ref, bt_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-                   o_ref, acc, m_s, l_s, *, ps, n_pages, length, window,
-                   scale):
-    b, p = pl.program_id(0), pl.program_id(2)
-
-    @pl.when(p == 0)
-    def _():
-        acc[...] = jnp.zeros_like(acc)
-        m_s[...] = jnp.full_like(m_s, NEG_INF)
-        l_s[...] = jnp.zeros_like(l_s)
-
-    pos = pos_ref[b]
-
-    @pl.when((p * ps <= pos) & (p * ps < length))
-    def _():
-        q = q_ref[0, 0].astype(jnp.float32)                    # (G, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32) * ks_ref[0, 0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        s = s + _page_mask(pos, p, ps, length, window)
-        _online_update(s, v_ref[0, :, 0, :].astype(jnp.float32)
-                       * vs_ref[0, 0], acc, m_s, l_s)
-
-    @pl.when(p == n_pages - 1)
-    def _():
-        o_ref[0, 0] = (acc[...] / jnp.maximum(l_s[...], 1e-30)
-                       ).astype(o_ref.dtype)
+    return _gqa_call(q, pool_k, pool_v, (), block_tables, pos,
+                     length=length, window=window, interpret=interpret)
 
 
 @functools.partial(jax.jit,
@@ -182,45 +177,8 @@ def paged_gqa_fwd_q8(q, pool_k, pool_v, k_scale, v_scale, block_tables,
 
     q: (B, H, hd); pool_k/v: (P, page, KV, hd) int8; k/v_scale: (P, KV)
     float32 -> (B, H, hd) in q.dtype."""
-    B, H, hd = q.shape
-    _P, ps, KV, _ = pool_k.shape
-    G = H // KV
-    n_pages = -(-length // ps)
-    bt = block_tables[:, :n_pages].astype(jnp.int32)
-    qg = q.reshape(B, KV, G, hd)
-    kern = functools.partial(_gqa_kernel_q8, ps=ps, n_pages=n_pages,
-                             length=length, window=window,
-                             scale=1.0 / (hd ** 0.5))
-    kv_map = lambda b, kv, p, pos_ref, bt_ref: (bt_ref[b, p], 0, kv, 0)
-    sc_map = lambda b, kv, p, pos_ref, bt_ref: (bt_ref[b, p], kv)
-    q_map = lambda b, kv, p, pos_ref, bt_ref: (b, kv, 0, 0)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, KV, n_pages),
-        in_specs=[
-            pl.BlockSpec((1, 1, G, hd), q_map),
-            pl.BlockSpec((1, ps, 1, hd), kv_map),
-            pl.BlockSpec((1, ps, 1, hd), kv_map),
-            pl.BlockSpec((1, 1), sc_map),
-            pl.BlockSpec((1, 1), sc_map),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, hd), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((G, hd), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kern, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-        name="paged_gqa_decode_q8",
-    )(pos.astype(jnp.int32), bt, qg, pool_k, pool_v,
-      k_scale.astype(jnp.float32), v_scale.astype(jnp.float32))
-    return out.reshape(B, H, hd)
+    return _gqa_call(q, pool_k, pool_v, (k_scale, v_scale), block_tables,
+                     pos, length=length, window=window, interpret=interpret)
 
 
 def _mla_kernel(pos_ref, bt_ref, qa_ref, qr_ref, ckv_ref, kr_ref, o_ref,
@@ -288,7 +246,7 @@ def paged_mla_fwd(q_abs, q_rope, pool_ckv, pool_krope, block_tables, pos,
     return pl.pallas_call(
         kern, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, r), q_abs.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="paged_mla_decode",
@@ -312,8 +270,8 @@ def _mla_kernel_q8(pos_ref, bt_ref, qa_ref, qr_ref, ckv_ref, kr_ref,
     def _():
         qa = qa_ref[0].astype(jnp.float32)                     # (H, r)
         qr = qr_ref[0].astype(jnp.float32)                     # (H, dr)
-        ckv = ckv_ref[0].astype(jnp.float32) * cs_ref[0, 0]    # (ps, r)
-        kr = kr_ref[0].astype(jnp.float32) * rs_ref[0, 0]      # (ps, dr)
+        ckv = ckv_ref[0].astype(jnp.float32) * cs_ref[0]       # (ps, r)
+        kr = kr_ref[0].astype(jnp.float32) * rs_ref[0]         # (ps, dr)
         s = (jax.lax.dot_general(qa, ckv, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
              + jax.lax.dot_general(qr, kr, (((1,), (1,)), ((), ())),
@@ -344,7 +302,7 @@ def paged_mla_fwd_q8(q_abs, q_rope, pool_ckv, pool_krope, ckv_scale,
     kern = functools.partial(_mla_kernel_q8, ps=ps, n_pages=n_pages,
                              length=length, scale=scale)
     page_map = lambda b, p, pos_ref, bt_ref: (bt_ref[b, p], 0, 0)
-    sc_map = lambda b, p, pos_ref, bt_ref: (bt_ref[b, p], 0)
+    sc_map = lambda b, p, pos_ref, bt_ref: (bt_ref[b, p], 0, 0)
     q_map = lambda b, p, pos_ref, bt_ref: (b, 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -354,8 +312,9 @@ def paged_mla_fwd_q8(q_abs, q_rope, pool_ckv, pool_krope, ckv_scale,
             pl.BlockSpec((1, H, dr), q_map),
             pl.BlockSpec((1, ps, r), page_map),
             pl.BlockSpec((1, ps, dr), page_map),
-            pl.BlockSpec((1, 1), sc_map),
-            pl.BlockSpec((1, 1), sc_map),
+            # scales as (P, 1, 1): a (1, 1) block of (P, 1) is refused
+            pl.BlockSpec((1, 1, 1), sc_map),
+            pl.BlockSpec((1, 1, 1), sc_map),
         ],
         out_specs=pl.BlockSpec((1, H, r), q_map),
         scratch_shapes=[
@@ -367,10 +326,10 @@ def paged_mla_fwd_q8(q_abs, q_rope, pool_ckv, pool_krope, ckv_scale,
     return pl.pallas_call(
         kern, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, r), q_abs.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="paged_mla_decode_q8",
     )(pos.astype(jnp.int32), bt, q_abs, q_rope, pool_ckv, pool_krope,
-      ckv_scale.astype(jnp.float32).reshape(-1, 1),
-      krope_scale.astype(jnp.float32).reshape(-1, 1))
+      ckv_scale.astype(jnp.float32).reshape(-1, 1, 1),
+      krope_scale.astype(jnp.float32).reshape(-1, 1, 1))

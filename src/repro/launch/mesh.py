@@ -8,13 +8,14 @@ from __future__ import annotations
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 single-pod (256 chips) or 2×16×16 two-pod (512 chips) mesh."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh(model: int = 1, data: int = 1):
@@ -36,8 +37,12 @@ def make_local_mesh(model: int = 1, data: int = 1):
             f"only {n} are available (set "
             f"XLA_FLAGS=--xla_force_host_platform_device_count=N to fake "
             f"more on CPU)")
+    # Auto axes: the engine places arrays with explicit NamedShardings and
+    # lets the compiler propagate the rest (jax.make_mesh defaults to
+    # Explicit axes, under which the vocab-sharded embedding gather raises)
     return jax.make_mesh((data, model), ("data", "model"),
-                         devices=devices[:need])
+                         devices=devices[:need],
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def mesh_info(mesh) -> dict:

@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.configs import SamplingParams, ServeConfig, get_config
 from repro.launch.mesh import make_local_mesh, mesh_info
 from repro.models import build_model
@@ -215,6 +216,7 @@ def main(argv=None):
     ap.add_argument("--baseline", action="store_true",
                     help="run the static-batch loop instead of the engine")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if min(args.requests, args.gen, args.prompt_len, args.slots) < 1:
         ap.error("--requests, --gen, --prompt-len and --slots must all "
                  "be >= 1")

@@ -16,6 +16,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.data import SyntheticLMDataset, ShardedLoader
 from repro.models import build_model
@@ -37,6 +38,7 @@ def main(argv=None):
     ap.add_argument("--ckpt-dir", default="/tmp/repro_train_ckpt")
     ap.add_argument("--ckpt-every", type=int, default=100)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch + ("-smoke" if args.smoke else ""))
     model = build_model(cfg)
