@@ -1,0 +1,7 @@
+"""Device: share of the traced window in which no operation ran on the
+chip (1 - busy / window, from the device trace).  Moves prompt_tok_s."""
+from benchmarks.onchip.reduce import idle_share
+
+
+def read(ctx):
+    return idle_share(ctx)
