@@ -6,8 +6,10 @@ the JSON schema Chrome's ``about:tracing`` and Perfetto
 inspected as a timeline: one track per request, one track for the
 engine's admission/decode waves, counter tracks for pool occupancy.
 
-Every timestamp is host wall time (``perf_counter`` microseconds,
-relative to recorder construction).  Nothing here ever touches device
+Every timestamp is host wall time: ``ts`` is microseconds of the
+recorder's clock (``time.perf_counter`` by default, the serving
+program's one host clock) since ``TraceRecorder.t0``, so
+``t0 + ts * 1e-6`` is back on that clock.  Nothing here ever touches device
 state or jitted programs: recording is append-to-a-python-list, and the
 serving engine only calls in around (never inside) its device calls —
 see :mod:`repro.serve.telemetry` for the contract.
@@ -30,9 +32,18 @@ from __future__ import annotations
 
 import json
 import time
-from typing import Any, Dict, List
+from typing import Any, Dict, List, NamedTuple
 
-__all__ = ["TraceRecorder", "validate_chrome_trace"]
+__all__ = ["TraceRecorder", "RecordedSpan", "validate_chrome_trace"]
+
+
+class RecordedSpan(NamedTuple):
+    """One closed B/E span of a track, on the recorder's clock."""
+    name: str
+    start: float          # seconds, on the recorder's clock
+    end: float
+    args: Dict[str, Any]  # begin args updated by end args
+    parent: int           # index of the enclosing span, -1 at the top
 
 
 class TraceRecorder:
@@ -47,7 +58,7 @@ class TraceRecorder:
     def __init__(self, *, pid: int = 0, clock=time.perf_counter):
         self.pid = int(pid)
         self._clock = clock
-        self._t0 = clock()
+        self.t0 = clock()                 # the clock reading ts counts from
         self.events: List[Dict[str, Any]] = []
         self._named_tids: set = set()
 
@@ -56,7 +67,7 @@ class TraceRecorder:
 
     def now_us(self) -> float:
         """Microseconds since recorder construction (the ``ts`` base)."""
-        return (self._clock() - self._t0) * 1e6
+        return (self._clock() - self.t0) * 1e6
 
     def thread_name(self, tid: int, name: str):
         """Label a track (idempotent): Perfetto shows this instead of a
@@ -90,6 +101,35 @@ class TraceRecorder:
         self.events.append({"ph": "C", "name": str(name),
                             "ts": self.now_us(), "pid": self.pid,
                             "tid": int(tid), "args": values})
+
+    def spans(self, tid: int = 0, since: float = float("-inf"),
+              until: float = float("inf")) -> List[RecordedSpan]:
+        """The closed spans of track ``tid`` that begin in ``[since,
+        until)`` on the recorder's clock, in order of their begin, each
+        with its enclosing span's index (-1 where that span is left
+        out: still open, or begun outside the bounds)."""
+        out: List[Any] = []               # None until the span closes
+        stack: List[int] = []             # indices of the open spans
+        begins: List[Dict[str, Any]] = []
+        for ev in self.events:
+            if ev.get("tid") != tid or ev["ph"] not in "BE":
+                continue
+            if ev["ph"] == "B":
+                stack.append(len(out))
+                out.append(None)
+                begins.append(ev)
+                continue
+            i = stack.pop()
+            b = begins[i]
+            out[i] = RecordedSpan(b["name"], self.t0 + b["ts"] * 1e-6,
+                                  self.t0 + ev["ts"] * 1e-6,
+                                  {**b["args"], **ev["args"]},
+                                  stack[-1] if stack else -1)
+        keep = [i for i, sp in enumerate(out)
+                if sp is not None and since <= sp.start < until]
+        new = {i: n for n, i in enumerate(keep)}
+        return [out[i]._replace(parent=new.get(out[i].parent, -1))
+                for i in keep]
 
     def to_json(self) -> Dict[str, Any]:
         """The JSON-object form of the Trace Event Format (the one with

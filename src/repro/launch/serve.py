@@ -356,6 +356,8 @@ def main(argv=None):
     if args.metrics:
         print("metrics:", json.dumps(eng.metrics(), indent=2,
                                      sort_keys=True))
+    if telemetry is not None:
+        telemetry.close()
     print("sample:", done[0].tokens[:16])
     return done
 

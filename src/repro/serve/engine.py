@@ -209,6 +209,8 @@ class ServeEngine:
         # telemetry
         self.n_steps = 0
         self.n_emitted = 0          # all tokens, incl. admission prefill
+        self.n_admitted = 0         # requests placed in a slot (or resumed)
+        self.n_prefill_tokens = 0   # prompt tokens prefilled (rows x length)
         self._n_decoded = 0         # tokens emitted by slot-batch steps
         self.n_prefix_hits = 0      # admissions that attached to cache
         self.n_prefix_tokens = 0    # prompt positions skipped by attaches
@@ -222,9 +224,6 @@ class ServeEngine:
         # decoded) per step, and submit->admission waits in milliseconds
         self._rate = RateWindow(maxlen=256)
         self._queue_wait = PercentileWindow(maxlen=512)
-        # jit compile counts last seen, per program — deltas become
-        # telemetry jit_compiles events (metrics() reads live counts)
-        self._jit_seen: Dict[str, int] = {}
         self._verbose_sink: Optional[StatsSink] = None
 
     def _check_spec_compat(self, step_model, drafter, prefix_cache):
@@ -398,7 +397,7 @@ class ServeEngine:
                       priority=priority, deadline=deadline, spec_k=spec_k)
         req.validate_scheduling()          # raises BEFORE the uid burns
         self._uid += 1
-        req.submit_t = time.monotonic()
+        req.submit_t = time.perf_counter()
         req.created_t = req.submit_t       # TTFT/e2e anchor (never reset)
         self.st.waiting.append(req)
         tel = self.telemetry
@@ -485,8 +484,9 @@ class ServeEngine:
                     self._pages_for_req(req)):
                 break                      # defer until pages free up
             st.pop_waiting(req)
+            self.n_admitted += 1
             if req.submit_t is not None:
-                wait_ms = (time.monotonic() - req.submit_t) * 1000.0
+                wait_ms = (time.perf_counter() - req.submit_t) * 1000.0
                 self._queue_wait.push(wait_ms)
                 if self.telemetry.enabled:
                     self.telemetry.observe("queue_wait_ms", wait_ms)
@@ -526,7 +526,7 @@ class ServeEngine:
         tel = self.telemetry
         for plen, group in groups.items():
             cw = self.sm.chunk_for(plen)
-            t0 = time.monotonic() if tel.enabled else 0.0
+            t0 = time.perf_counter() if tel.enabled else 0.0
             with tel.span("prefill", plen=plen, wave=len(group),
                           chunk_w=cw, chunks=-(-plen // cw)) as sp:
                 pages = None
@@ -534,31 +534,38 @@ class ServeEngine:
                     req0, slot0 = group[0]  # singleton wave (see above)
                     pages, attach = self.prefix_cache.match(
                         req0.prompt, cw)
-                if pages is not None:
-                    last, carry = self._attach_prefill(req0, slot0,
-                                                       pages, attach)
-                    sp.set(attached=attach)
-                else:
-                    if self.pool is not None:
-                        for _r, s in group:
-                            self.pool.grow(s, self.sm.pages_for(plen))
-                    prompts = [r.prompt for r, _s in group]
-                    prompts += [prompts[-1]] * (
-                        len(self._pad_slots([s for _r, s in group]))
-                        - len(group))
-                    last, carry = self.sm.prefill(self.params,
-                                                  np.stack(prompts))
+                with tel.span("prefill.run") as run:
+                    if pages is not None:
+                        last, carry, start = self._attach_prefill(
+                            req0, slot0, pages, attach)
+                        sp.set(attached=attach)
+                    else:
+                        start = 0
+                        if self.pool is not None:
+                            for _r, s in group:
+                                self.pool.grow(s, self.sm.pages_for(plen))
+                        prompts = [r.prompt for r, _s in group]
+                        prompts += [prompts[-1]] * (
+                            len(self._pad_slots([s for _r, s in group]))
+                            - len(group))
+                        last, carry = self.sm.prefill(self.params,
+                                                      np.stack(prompts))
+                    self.n_prefill_tokens += len(group) * (plen - start)
+                    if tel.enabled:
+                        run.set(rows=len(group),
+                                chunks=-(-(plen - start) // cw))
                 self._install_wave(plen, group, last, carry)
             if tel.enabled:
                 tel.observe("prefill_ms",
-                            (time.monotonic() - t0) * 1000.0)
+                            (time.perf_counter() - t0) * 1000.0)
         return True
 
     def _attach_prefill(self, req, slot, pages, attach):
         """Prefix-cache hit: share the resident pages into ``slot``,
         reconstruct the dense cache they hold, and prefill only the tail
-        chunks — the attached stream is bitwise the stream a full
-        prefill would have produced (same chunk grid, same bytes)."""
+        chunks from ``start`` — the attached stream is bitwise the stream
+        a full prefill would have produced (same chunk grid, same bytes).
+        Returns (last, carry, start)."""
         sm, plen = self.sm, len(req.prompt)
         self.pool.share(slot, pages)
         # gather BEFORE any detach below rewires the block-table row
@@ -582,49 +589,55 @@ class ServeEngine:
                                  cache0=seed, start=start)
         self.n_prefix_hits += 1
         self.n_prefix_tokens += start
-        return last, carry
+        return last, carry, start
 
     def _install_wave(self, plen, group, last, carry):
         """Scatter a prefilled wave into its slots, pin its prompts in
         the prefix cache, and draw/book-keep the first sampled token."""
         st = self.st
+        tel = self.telemetry
         slots = [s for _r, s in group]
         pad = self._pad_slots(slots)
-        if self.pool is None:
-            self.state = self.sm.write_slots(self.state, carry, pad)
-        else:
-            # page-granular scatter: each wave row's dense prefill
-            # cache lands in its chain's pages; padding rows get
-            # all-out-of-bounds page ids so their writes drop
-            pages = np.full((len(pad), self.pool.max_pages),
-                            self.pool.num_pages, np.int32)
-            pages[:len(group)] = self.pool.block_tables[slots]
-            self.state = self.sm.write_slots(self.state, carry, pad,
-                                             pages=pages, plen=plen)
-            if self.prefix_cache is not None:
-                # pin BEFORE an instant retire below releases the chain
-                for r, s in group:
-                    self.prefix_cache.insert(
-                        r.prompt, self.pool.block_tables[s],
-                        self.sm.chunk_for(plen))
-        if self.drafter is not None:
-            # the drafter tracks the SAME stream: prefill its own carry
-            # over the wave's prompts (same padded batch — padding rows
-            # land at OOB slots and drop) and tile it K-wide, resume
-            # index 0.  The target draws tok0 below; the drafter will
-            # consume it as ``cur`` in the first propose wave.
-            prompts = [r.prompt for r, _s in group]
-            prompts += [prompts[-1]] * (len(pad) - len(group))
-            carry = self.drafter.prefill(self.draft_params,
-                                         np.stack(prompts))
-            self.draft_store = self.drafter.install(self.draft_store,
-                                                    carry, pad)
-        # the wave's first generated token sits at position plen — its
-        # draw uses the same counter-based (seed, uid, pos) key family
-        # as the decode loop, so it is reproducible under any batching
-        tok0 = np.asarray(self.sm.sample(
-            last, self._wave_sampling(group, len(pad)),
-            np.full(len(pad), plen, np.int32)))
+        with tel.span("prefill.install"):
+            if self.pool is None:
+                self.state = self.sm.write_slots(self.state, carry, pad)
+            else:
+                # page-granular scatter: each wave row's dense prefill
+                # cache lands in its chain's pages; padding rows get
+                # all-out-of-bounds page ids so their writes drop
+                pages = np.full((len(pad), self.pool.max_pages),
+                                self.pool.num_pages, np.int32)
+                pages[:len(group)] = self.pool.block_tables[slots]
+                self.state = self.sm.write_slots(self.state, carry, pad,
+                                                 pages=pages, plen=plen)
+                if self.prefix_cache is not None:
+                    # pin BEFORE an instant retire below releases the chain
+                    for r, s in group:
+                        self.prefix_cache.insert(
+                            r.prompt, self.pool.block_tables[s],
+                            self.sm.chunk_for(plen))
+            if self.drafter is not None:
+                # the drafter tracks the SAME stream: prefill its own
+                # carry over the wave's prompts (same padded batch —
+                # padding rows land at OOB slots and drop) and tile it
+                # K-wide, resume index 0.  The target draws tok0 below;
+                # the drafter will consume it as ``cur`` in the first
+                # propose wave.
+                prompts = [r.prompt for r, _s in group]
+                prompts += [prompts[-1]] * (len(pad) - len(group))
+                carry = self.drafter.prefill(self.draft_params,
+                                             np.stack(prompts))
+                self.draft_store = self.drafter.install(self.draft_store,
+                                                        carry, pad)
+            # the wave's first generated token sits at position plen —
+            # its draw uses the same counter-based (seed, uid, pos) key
+            # family as the decode loop, so it is reproducible under any
+            # batching
+            tok0 = self.sm.sample(last,
+                                  self._wave_sampling(group, len(pad)),
+                                  np.full(len(pad), plen, np.int32))
+        with tel.span("prefill.sync"):
+            tok0 = np.asarray(tok0)
         for i, (req, slot) in enumerate(group):
             t = int(tok0[i])
             req.outputs.append(t)
@@ -676,7 +689,7 @@ class ServeEngine:
                 req.snapshot["draft"] = self.drafter.snapshot_slot(
                     self.draft_store, slot)
                 req.snapshot["draft_sel"] = int(self._draft_sel[slot])
-            req.submit_t = time.monotonic()  # queue wait restarts here
+            req.submit_t = time.perf_counter()  # queue wait restarts here
             req.n_preemptions += 1
             self.n_preemptions += 1
             st.free_slot(slot)             # pages + reservation go back
@@ -725,7 +738,7 @@ class ServeEngine:
         """Book the request's first emitted token (TTFT anchor)."""
         if req.first_token_t is not None:
             return
-        req.first_token_t = time.monotonic()
+        req.first_token_t = time.perf_counter()
         if self.telemetry.enabled and req.created_t is not None:
             self.telemetry.observe(
                 "ttft_ms", (req.first_token_t - req.created_t) * 1000.0)
@@ -785,37 +798,55 @@ class ServeEngine:
 
         All telemetry here is host-side wall clock + host counters
         around the device call — the jitted program and its inputs are
-        byte-identical with telemetry on or off."""
+        byte-identical with telemetry on or off.  The ``step`` span
+        covers the whole call; its end args say what the step admitted
+        and prefilled and what it left running.  A step's host time is
+        its duration less the ``*.sync`` spans inside it (the host
+        waiting on the device)."""
+        tel = self.telemetry
+        n_admitted, n_prefill = self.n_admitted, self.n_prefill_tokens
+        with tel.span("step") as sp:
+            self._step()
+            if tel.enabled:
+                st, pool = self.st, self.pool
+                sp.set(admitted=self.n_admitted - n_admitted,
+                       prefill_tokens=self.n_prefill_tokens - n_prefill,
+                       active_slots=st.n_active, slots=self.slots,
+                       queue_depth=st.queue_depth,
+                       pages_reserved=pool.reserved_total if pool else 0,
+                       pages_in_use=pool.pages_in_use if pool else 0,
+                       preemptions=self.n_preemptions,
+                       compiles=tel.registry.counters.get("compiles", 0))
+
+    def _step(self):
         tel = self.telemetry
         with tel.span("admit", queue_depth=self.st.queue_depth):
             self.admit()
         st = self.st
         if not st.active.any():
-            if tel.enabled:
-                self._note_compiles()
             return
-        t0 = time.monotonic()
+        t0 = time.perf_counter()
         d0, a0 = self._n_decoded, self.n_drafts_accepted
         spec = self.drafter is not None
+        args = {}
+        if tel.enabled:
+            args = dict(active_slots=st.n_active, slots=self.slots,
+                        ctx_tokens=int((st.pos[st.active] + 1).sum()),
+                        queue_depth=st.queue_depth,
+                        pages_in_use=(self.pool.pages_in_use
+                                      if self.pool else 0))
         with tel.span("spec_wave" if spec else "decode_wave",
-                      active_slots=st.n_active,
-                      queue_depth=st.queue_depth,
-                      pages_in_use=(self.pool.pages_in_use
-                                    if self.pool else 0)) as sp:
+                      **args) as sp:
             if spec:
                 self._spec_step()
                 sp.set(accepted_drafts=self.n_drafts_accepted - a0)
             else:
                 self._plain_step()
             sp.set(tokens=self._n_decoded - d0)
-        now = time.monotonic()
+        now = time.perf_counter()
         self._rate.push(now, self._n_decoded - d0)
         if tel.enabled:
-            wave_ms = (now - t0) * 1000.0
-            tel.observe("step_ms", wave_ms)
-            # per-stream inter-token latency: one wave = one emission
-            # opportunity per active slot (>= 1 token under spec)
-            tel.observe("itl_ms", wave_ms)
+            tel.observe("step_ms", (now - t0) * 1000.0)
             tel.inc("decode_waves")
             tel.inc("tokens_decoded", self._n_decoded - d0)
             tel.gauge("active_slots", st.n_active)
@@ -828,63 +859,74 @@ class ServeEngine:
                 tel.counter("pool", in_use=self.pool.pages_in_use,
                             free=len(self.pool._free),
                             reserved=self.pool.reserved_total)
-            self._note_compiles()
+
+    def _grow_pages(self, widths):
+        """Before a wave that writes K/V at ``pos .. pos + width - 1`` of
+        every active slot: grow each chain to cover it and detach shared
+        pages the writes land in (copy-on-write).  The pages come out of
+        the reservation made at admission, so growth cannot fail
+        mid-stream; the device copies of the whole wave run as ONE
+        jitted program."""
+        st = self.st
+        cow_src, cow_dst = [], []
+        for slot in np.flatnonzero(st.active):
+            p0, kk = int(st.pos[slot]), int(widths[slot])
+            self.pool.grow(slot, self.sm.pages_for(p0 + kk))
+            touched = set()
+            for p in range(p0, p0 + kk):
+                touched.update(self.sm.write_page_indices(p))
+            for ci in sorted(touched):
+                pair = self.pool.cow(slot, ci)
+                if pair is not None:
+                    cow_src.append(pair[0])
+                    cow_dst.append(pair[1])
+        if cow_src:
+            self.state = self.sm.copy_pages(self.state, cow_src, cow_dst)
+            self.n_cow_copies += len(cow_src)
 
     def _plain_step(self):
         """One slot-batched decode step (no drafter)."""
         st = self.st
-        bt = None
-        if self.pool is not None:
-            # allocate-on-decode-append: this step writes K/V at
-            # pos[slot], so every active chain must cover it — the pages
-            # come out of the reservation made at admission, so growth
-            # cannot fail mid-stream.  Copy-on-write: a write landing in
-            # a SHARED page (fork sibling / prefix-cache pin also holds
-            # it) first detaches to a private copy; the device copies
-            # for the whole step batch run as ONE jitted program.
-            cow_src, cow_dst = [], []
-            for slot in np.flatnonzero(st.active):
-                self.pool.grow(slot,
-                               self.sm.pages_for(int(st.pos[slot]) + 1))
-                for ci in self.sm.write_page_indices(int(st.pos[slot])):
-                    pair = self.pool.cow(slot, ci)
-                    if pair is not None:
-                        cow_src.append(pair[0])
-                        cow_dst.append(pair[1])
-            if cow_src:
-                self.state = self.sm.copy_pages(self.state, cow_src,
-                                                cow_dst)
-                self.n_cow_copies += len(cow_src)
-            bt = self.pool.block_tables
-        active = jnp.asarray(st.active)
-        pos = jnp.asarray(st.pos)
-        x = jnp.asarray(st.cur)
-        sampling = None
-        if self.sm.autoregressive:
-            sampling = {k: jnp.asarray(v) for k, v in st.knobs.items()}
-        kw = {} if bt is None else {"bt": bt}
-        out, self.state = self.sm.step(self.params, x, self.state, pos,
-                                       active, sampling, **kw)
-        emitted = np.asarray(out)
-        self.n_steps += 1
-        for slot in np.flatnonzero(st.active):
-            req = st.slot_req[slot]
-            req.outputs.append(emitted[slot].copy())
-            self.n_emitted += 1
-            self._n_decoded += 1
-            self._first_token(req)
-            st.pos[slot] += 1
-            st.remaining[slot] -= 1
+        tel = self.telemetry
+        with tel.span("decode.prepare"):
+            bt = None
+            if self.pool is not None:
+                # allocate-on-decode-append: this step writes K/V at
+                # pos[slot] of every active slot
+                self._grow_pages(np.ones(self.slots, np.int32))
+                bt = self.pool.block_tables
+            active = jnp.asarray(st.active)
+            pos = jnp.asarray(st.pos)
+            x = jnp.asarray(st.cur)
+            sampling = None
             if self.sm.autoregressive:
-                st.cur[slot] = emitted[slot]
-                done = (st.remaining[slot] <= 0
-                        or emitted[slot] == req.eos_id)
-            else:
-                done = st.remaining[slot] <= 0
-                if not done:
-                    st.cur[slot] = req.prompt[st.pos[slot]]
-            if done:
-                self._retire(slot)
+                sampling = {k: jnp.asarray(v) for k, v in st.knobs.items()}
+            kw = {} if bt is None else {"bt": bt}
+        with tel.span("decode.dispatch"):
+            out, self.state = self.sm.step(self.params, x, self.state, pos,
+                                           active, sampling, **kw)
+        with tel.span("decode.sync"):
+            emitted = np.asarray(out)
+        self.n_steps += 1
+        with tel.span("decode.book"):
+            for slot in np.flatnonzero(st.active):
+                req = st.slot_req[slot]
+                req.outputs.append(emitted[slot].copy())
+                self.n_emitted += 1
+                self._n_decoded += 1
+                self._first_token(req)
+                st.pos[slot] += 1
+                st.remaining[slot] -= 1
+                if self.sm.autoregressive:
+                    st.cur[slot] = emitted[slot]
+                    done = (st.remaining[slot] <= 0
+                            or emitted[slot] == req.eos_id)
+                else:
+                    done = st.remaining[slot] <= 0
+                    if not done:
+                        st.cur[slot] = req.prompt[st.pos[slot]]
+                if done:
+                    self._retire(slot)
 
     def _spec_step(self):
         """One propose/verify wave: the drafter rolls ``spec_k`` greedy
@@ -898,73 +940,69 @@ class ServeEngine:
         serve every traffic mix — per-slot widths, positions and
         sampling knobs are data."""
         st = self.st
-        # per-slot verify widths: the request's own spec_k clamped by the
-        # remaining budget, so commits never pass pos + remaining (the
-        # reservation and the max_len bound stop exactly there)
-        k_slot = heterogeneous_k(self._req_k, st.remaining, self.spec_k)
-        # a wave writes K/V at pos .. pos+k_slot-1: grow/COW the whole
-        # span up front (same reservation-backed guarantee as one step)
-        cow_src, cow_dst = [], []
-        for slot in np.flatnonzero(st.active):
-            p0, kk = int(st.pos[slot]), int(k_slot[slot])
-            self.pool.grow(slot, self.sm.pages_for(p0 + kk))
-            touched = set()
-            for p in range(p0, p0 + kk):
-                touched.update(self.sm.write_page_indices(p))
-            for ci in sorted(touched):
-                pair = self.pool.cow(slot, ci)
-                if pair is not None:
-                    cow_src.append(pair[0])
-                    cow_dst.append(pair[1])
-        if cow_src:
-            self.state = self.sm.copy_pages(self.state, cow_src, cow_dst)
-            self.n_cow_copies += len(cow_src)
         tel = self.telemetry
-        active = jnp.asarray(st.active)
-        pos = jnp.asarray(st.pos)
+        with tel.span("decode.prepare"):
+            # per-slot verify widths: the request's own spec_k clamped by
+            # the remaining budget, so commits never pass pos + remaining
+            # (the reservation and the max_len bound stop exactly there)
+            k_slot = heterogeneous_k(self._req_k, st.remaining,
+                                     self.spec_k)
+            # a wave writes K/V at pos .. pos+k_slot-1: grow/COW the
+            # whole span up front (same guarantee as one step)
+            self._grow_pages(k_slot)
+            active = jnp.asarray(st.active)
+            pos = jnp.asarray(st.pos)
+            sampling = {k: jnp.asarray(v) for k, v in st.knobs.items()}
         with tel.span("propose", k=int(k_slot.max())):
             toks, self.draft_store = self.drafter.propose(
                 self.draft_params, self.draft_store, self._draft_sel,
                 np.asarray(st.cur), active)
-        sampling = {k: jnp.asarray(v) for k, v in st.knobs.items()}
         with tel.span("verify"):
             emitted, n_emit, self.state = self.sm.verify(
                 self.params, toks, self.state, pos, active,
                 k_slot, sampling, bt=self.pool.block_tables)
-        emitted = np.asarray(emitted)
-        n_emit = np.asarray(n_emit)
+        with tel.span("decode.sync"):
+            emitted = np.asarray(emitted)
+            n_emit = np.asarray(n_emit)
         self.n_steps += 1
-        for slot in np.flatnonzero(st.active):
-            req = st.slot_req[slot]
-            n = int(n_emit[slot])
-            self.n_drafts_proposed += int(k_slot[slot]) - 1
-            self.n_drafts_accepted += n - 1
-            done = False
-            n_take = n
-            for j in range(n):
-                t = int(emitted[slot, j])
-                req.outputs.append(emitted[slot, j].copy())
-                self.n_emitted += 1
-                self._n_decoded += 1
-                self._first_token(req)
-                if t == req.eos_id:
-                    # tokens past an eos are discarded — target-only
-                    # decode would never have produced them (their K/V
-                    # commits die with the freed pages)
-                    n_take = j + 1
-                    done = True
-                    break
-            st.pos[slot] += n_take
-            st.remaining[slot] -= n_take
-            if st.remaining[slot] <= 0:
+        with tel.span("decode.book"):
+            for slot in np.flatnonzero(st.active):
+                self._book_spec_slot(slot, int(n_emit[slot]),
+                                     int(k_slot[slot]), emitted[slot])
+
+    def _book_spec_slot(self, slot, n, k, emitted):
+        """Advance ``slot`` by the ``n`` tokens its verify accepted (of
+        ``k`` offered), stopping at an eos."""
+        st = self.st
+        req = st.slot_req[slot]
+        self.n_drafts_proposed += k - 1
+        self.n_drafts_accepted += n - 1
+        done = False
+        n_take = n
+        for j in range(n):
+            t = int(emitted[j])
+            req.outputs.append(emitted[j].copy())
+            self.n_emitted += 1
+            self._n_decoded += 1
+            self._first_token(req)
+            if t == req.eos_id:
+                # tokens past an eos are discarded — target-only decode
+                # would never have produced them (their K/V commits die
+                # with the freed pages)
+                n_take = j + 1
                 done = True
-            if done:
-                self._retire(slot)
-            else:
-                st.cur[slot] = emitted[slot, n_take - 1]
-                # resume carry: the drafter state after consuming the
-                # stream through pos-1 is the wave's (n_take-1)-th feed
-                self._draft_sel[slot] = n_take - 1
+                break
+        st.pos[slot] += n_take
+        st.remaining[slot] -= n_take
+        if st.remaining[slot] <= 0:
+            done = True
+        if done:
+            self._retire(slot)
+        else:
+            st.cur[slot] = emitted[n_take - 1]
+            # resume carry: the drafter state after consuming the stream
+            # through pos-1 is the wave's (n_take-1)-th feed
+            self._draft_sel[slot] = n_take - 1
 
     def fork(self, req: Request, n: int = 1, *,
              max_new_tokens: Optional[int] = None,
@@ -1151,20 +1189,6 @@ class ServeEngine:
                     out["draft" + attr[len("_jit"):]] = fn
         return out
 
-    def _note_compiles(self):
-        """Diff jit cache sizes against the last observation; new
-        entries become ``jit_compiles`` counter increments and engine-
-        track instants.  Host-side observation only — reading
-        ``_cache_size()`` never triggers or prevents a compile."""
-        tel = self.telemetry
-        for name, fn in self._jit_programs().items():
-            n = fn._cache_size()
-            seen = self._jit_seen.get(name, 0)
-            if n > seen:
-                tel.inc("jit_compiles", n - seen)
-                tel.instant("jit_compile", program=name, cache_size=n)
-                self._jit_seen[name] = n
-
     def metrics(self) -> Dict[str, Any]:
         """Machine-readable engine metrics as a typed dict — the
         autoscaling-loop / dashboard readout.  Always available (the
@@ -1182,6 +1206,8 @@ class ServeEngine:
                 "steps": self.n_steps,
                 "tokens_emitted": self.n_emitted,
                 "tokens_decoded": self._n_decoded,
+                "requests_admitted": self.n_admitted,
+                "prefill_tokens": self.n_prefill_tokens,
                 "requests_finished": len(self.st.finished),
                 "preemptions": self.n_preemptions,
                 "forks": self.n_forks,

@@ -74,7 +74,7 @@ class Request:
     # what the queue-wait percentiles in EngineStats measure
     submit_t: Optional[float] = dataclasses.field(default=None,
                                                   repr=False)
-    # lifecycle timestamps (time.monotonic), set once each: submission
+    # lifecycle timestamps (time.perf_counter), set once each: submission
     # (never reset — the TTFT/e2e anchor), first emitted token, and
     # retirement.  What the ttft_ms / e2e_ms telemetry histograms read.
     created_t: Optional[float] = dataclasses.field(default=None,
@@ -205,7 +205,7 @@ class SlotTable:
     def retire(self, slot: int) -> Request:
         req = self.slot_req[slot]
         req.finished = True
-        req.finish_t = time.monotonic()
+        req.finish_t = time.perf_counter()
         self.finished.append(req)
         self.free_slot(slot)
         return req

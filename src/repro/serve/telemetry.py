@@ -11,7 +11,11 @@ Three pieces, all of them OFF the device path:
     optionally carries a :class:`~repro.common.trace.TraceRecorder`
     (Chrome trace_event JSON — request-lifecycle spans on one track per
     request, admission/decode waves on the engine track) and a
-    :class:`StatsSink` (the periodic stats line).
+    :class:`StatsSink` (the periodic stats line).  Its engine-track
+    spans also enter ``jax.profiler.TraceAnnotation("serve.<name>")``,
+    so under a profiler session they land in the same trace as the
+    device ops, on the device's clock; and it counts every backend
+    compile of the process (``compiles``) while it is open.
   * :data:`NULL_TELEMETRY` — the no-op default.  Every instrumentation
     site in the engine is either a method on this object (pure ``pass``)
     or guarded by ``telemetry.enabled``; a disabled engine pays an
@@ -33,17 +37,25 @@ them directly.
 from __future__ import annotations
 
 import sys
-import time
+import weakref
 from collections import deque
 from typing import Any, Dict, Optional
 
+import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.common.trace import TraceRecorder
 
 __all__ = ["Telemetry", "NullTelemetry", "NULL_TELEMETRY",
            "MetricsRegistry", "RateWindow", "PercentileWindow",
            "StatsSink"]
+
+#: engine-track span ``name`` is ``PROFILER_PREFIX + name`` in a profile
+PROFILER_PREFIX = "serve."
+# what jax.monitoring records around every backend compile (or fetch from
+# the persistent compilation cache), with the program's name as fun_name
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
 class RateWindow:
@@ -185,12 +197,15 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """Context-managed B/E pair; ``set()`` attaches end-time args
-    (counts known only when the wave finishes)."""
-    __slots__ = ("_tr", "name", "tid", "args", "end_args")
+    """Context-managed span with two sinks: a profiler annotation named
+    ``serve.<name>`` (a no-op unless a profiler session is open) and,
+    when a recorder is attached, its B/E pair; ``set()`` attaches
+    end-time args (counts known only when the wave finishes)."""
+    __slots__ = ("_tr", "_ann", "name", "tid", "args", "end_args")
 
     def __init__(self, tr, name, tid, args):
         self._tr = tr
+        self._ann = TraceAnnotation(PROFILER_PREFIX + name)
         self.name = name
         self.tid = tid
         self.args = args
@@ -200,11 +215,15 @@ class _Span:
         self.end_args.update(kw)
 
     def __enter__(self):
-        self._tr.begin(self.name, self.tid, **self.args)
+        self._ann.__enter__()
+        if self._tr is not None:
+            self._tr.begin(self.name, self.tid, **self.args)
         return self
 
     def __exit__(self, *exc):
-        self._tr.end(self.tid, name=self.name, **self.end_args)
+        if self._tr is not None:
+            self._tr.end(self.tid, name=self.name, **self.end_args)
+        self._ann.__exit__(*exc)
         return False
 
 
@@ -246,6 +265,9 @@ class NullTelemetry:
     def request_instant(self, req, name, **args):
         pass
 
+    def close(self):
+        pass
+
 
 #: Module-level singleton every component defaults to.
 NULL_TELEMETRY = NullTelemetry()
@@ -258,6 +280,12 @@ class Telemetry(NullTelemetry):
     recorder may be passed instead (tests inject a fake clock).
     ``stats_stream``/``stats_every`` configure the periodic stats-line
     sink (``run()`` drives it once per engine step).
+
+    While open, every backend compile in the process (jitted wrappers,
+    eager ops such as a ``jnp.pad`` per new shape, persistent-cache
+    fetches) becomes a ``compiles`` increment and, traced, a ``compile``
+    instant carrying the program's name and seconds.  ``close()`` stops
+    the count.
 
     Track layout: tid 0 is the engine (admission rounds, prefill waves,
     decode/spec waves, preempt/resume, pool counters); each request
@@ -285,6 +313,29 @@ class Telemetry(NullTelemetry):
         self._open: Dict[int, str] = {}   # uid -> open lifecycle span
         if self.trace is not None:
             self.trace.thread_name(self.ENGINE_TID, "engine")
+        # a weak hop: an unclosed handle stays collectable
+        ref = weakref.WeakMethod(self._on_duration)
+
+        def listener(event, secs, **kw):
+            fn = ref()
+            if fn is not None:
+                fn(event, secs, **kw)
+
+        self._listener = listener
+        jax.monitoring.register_event_duration_secs_listener(listener)
+
+    def _on_duration(self, event, secs, fun_name="", **_kw):
+        if event == _BACKEND_COMPILE_EVENT:
+            self.inc("compiles")
+            self.instant("compile", program=str(fun_name),
+                         seconds=float(secs))
+
+    def close(self):
+        """Stop counting compiles (idempotent)."""
+        if self._listener is not None:
+            jax.monitoring.unregister_event_duration_listener(
+                self._listener)
+            self._listener = None
 
     # -- metrics ---------------------------------------------------------
     def inc(self, name, n=1):
@@ -298,8 +349,6 @@ class Telemetry(NullTelemetry):
 
     # -- engine track ----------------------------------------------------
     def span(self, name, **args):
-        if self.trace is None:
-            return _NULL_SPAN
         return _Span(self.trace, name, self.ENGINE_TID, args)
 
     def instant(self, name, **args):

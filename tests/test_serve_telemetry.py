@@ -387,3 +387,216 @@ def test_traced_plain_engine_bitwise(lm):
         return [list(r.tokens) for r in reqs]
 
     assert go(Telemetry(trace=True)) == go(None)
+
+
+# -- engine spans: the step tree, the profiler sink, compile counting ----
+STEP_ARGS = {"admitted", "prefill_tokens", "active_slots", "slots",
+             "queue_depth", "pages_reserved", "pages_in_use",
+             "preemptions", "compiles"}
+
+
+def test_recorder_spans_on_the_host_clock():
+    """spans() rebuilds the B/E tree of a track on the recorder's clock:
+    t0 + ts, begin args updated by end args, parent indices; a span left
+    open, or begun outside the bounds, is dropped and its children become
+    tops."""
+    tr = TraceRecorder(clock=_fake_clock())
+    assert tr.t0 == pytest.approx(0.001)
+    tr.begin("outer", 0, a=1)             # ts 1000 us
+    tr.begin("inner", 0)
+    tr.end(0, name="inner", n=2)
+    tr.end(0, name="outer", b=3)
+    tr.begin("other track", 4)
+    tr.end(4)
+    tr.begin("open", 0)
+    tr.begin("child", 0)
+    tr.end(0, name="child")
+    sp = tr.spans()
+    assert [s.name for s in sp] == ["outer", "inner", "child"]
+    assert [s.parent for s in sp] == [-1, 0, -1]
+    assert sp[0].args == {"a": 1, "b": 3}
+    assert sp[1].args == {"n": 2}
+    assert sp[0].start == pytest.approx(tr.t0 + 0.001)
+    assert sp[0].end == pytest.approx(tr.t0 + 0.004)
+    assert sp[1].start >= sp[0].start and sp[1].end <= sp[0].end
+    assert [s.name for s in tr.spans(tid=4)] == ["other track"]
+    # bounds on the begin: a child whose parent begun earlier is a top
+    late = tr.spans(since=sp[1].start)
+    assert [(s.name, s.parent) for s in late] == [("inner", -1),
+                                                  ("child", -1)]
+    assert [s.name for s in tr.spans(until=sp[1].start)] == ["outer"]
+
+
+def _children(spans, i):
+    return [s.name for s in spans if s.parent == i]
+
+
+def _paged_lm():
+    cfg = dataclasses.replace(get_config("smollm-360m-smoke"),
+                              paged_impl="gather")
+    model = build_model(cfg)
+    return cfg, model, model.init(jax.random.PRNGKey(0))
+
+
+@pytest.mark.parametrize("layout", ["dense", "paged"])
+def test_step_span_tree(lm, layout, tmp_path):
+    """Every step is one `step` span holding its admission and decode
+    wave; the prefill and decode waves split into run/install/sync and
+    prepare/dispatch/sync/book; the args say what the step did."""
+    if layout == "dense":
+        cfg, model, params = lm
+        sm = DecoderStepModel(model, max_len=32, prefill_chunk=8)
+    else:
+        cfg, model, params = _paged_lm()
+        sm = DecoderStepModel(model, max_len=32, prefill_chunk=8,
+                              kv_layout="paged",
+                              paged=PagedConfig(page_size=4))
+    tel = Telemetry(trace=True)
+    eng = ServeEngine(sm, params, slots=2, telemetry=tel)
+    reqs = _submit_mixed(eng, cfg)
+    eng.run()
+    tel.close()
+    validate_chrome_trace(tel.trace.to_json())
+    sp = tel.trace.spans()
+    tops = [i for i, s in enumerate(sp) if s.parent == -1]
+    assert {sp[i].name for i in tops} == {"step"}
+    for i in tops:
+        assert STEP_ARGS <= set(sp[i].args)
+        assert sp[i].args["slots"] == 2
+        kids = _children(sp, i)
+        assert kids[0] == "admit" and set(kids[1:]) <= {"decode_wave"}
+    prefills = [i for i, s in enumerate(sp) if s.name == "prefill"]
+    assert prefills
+    for i in prefills:
+        assert sp[sp[i].parent].name == "admit"
+        assert _children(sp, i) == ["prefill.run", "prefill.install",
+                                    "prefill.sync"]
+        run = next(s for s in sp if s.parent == i)
+        assert run.args["rows"] == sp[i].args["wave"]
+        assert run.args["chunks"] == sp[i].args["chunks"]
+    waves = [i for i, s in enumerate(sp) if s.name == "decode_wave"]
+    assert len(waves) == eng.n_steps
+    for i in waves:
+        assert sp[sp[i].parent].name == "step"
+        assert _children(sp, i) == ["decode.prepare", "decode.dispatch",
+                                    "decode.sync", "decode.book"]
+        assert sp[i].args["ctx_tokens"] >= sp[i].args["active_slots"] > 0
+    # the step args sum to the run's totals
+    steps = [sp[i].args for i in tops]
+    assert sum(a["admitted"] for a in steps) == len(reqs)
+    assert sum(a["prefill_tokens"] for a in steps) == \
+        sum(len(r.prompt) for r in reqs)
+    assert steps[-1]["active_slots"] == 0
+    assert steps[-1]["preemptions"] == 0
+    comp = [a["compiles"] for a in steps]
+    assert comp[0] > 0 and comp == sorted(comp)
+    if layout == "paged":
+        assert max(a["pages_reserved"] for a in steps) > 0
+        assert max(a["pages_in_use"] for a in steps) > 0
+    m = eng.metrics()
+    assert m["counters"]["requests_admitted"] == len(reqs)
+    assert m["counters"]["prefill_tokens"] == \
+        sum(len(r.prompt) for r in reqs)
+    assert "itl_ms" not in m["telemetry"]["histograms"]
+    assert m["telemetry"]["histograms"]["step_ms"]["count"] == eng.n_steps
+
+
+def test_spec_step_span_tree(spec_models):
+    """The propose/verify wave splits the same way, and every step span
+    carries the preemptions so far."""
+    tel = Telemetry(trace=True)
+    eng, sm, reqs = _spec_engine(spec_models, telemetry=tel)
+    _drive_with_preempt(eng, sm, reqs)
+    tel.close()
+    sp = tel.trace.spans()
+    waves = [i for i, s in enumerate(sp) if s.name == "spec_wave"]
+    assert waves
+    for i in waves:
+        assert _children(sp, i) == ["decode.prepare", "propose", "verify",
+                                    "decode.sync", "decode.book"]
+    steps = [s.args for s in sp if s.name == "step"]
+    assert steps[0]["preemptions"] == 0
+    assert steps[-1]["preemptions"] == eng.n_preemptions > 0
+
+
+def _profiled_host_events(tmp_path, fn):
+    """Run fn under a CPU profiler session -> the host events as
+    (name, start_ns, end_ns)."""
+    import glob
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    return [(e.name, e.start_ns, e.end_ns)
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events]
+
+
+def test_engine_spans_land_in_the_profile(lm, tmp_path):
+    """Under a profiler session the engine's spans are host events named
+    serve.<name> that nest as the recorder's do."""
+    cfg, model, params = lm
+    tel = Telemetry(trace=True)
+    eng = ServeEngine(DecoderStepModel(model, max_len=32, prefill_chunk=8),
+                      params, slots=2, telemetry=tel)
+    _submit_mixed(eng, cfg)
+    evs = [e for e in _profiled_host_events(tmp_path, eng.run)
+           if e[0].startswith("serve.")]
+    tel.close()
+    names = {e[0] for e in evs}
+    assert {"serve.step", "serve.admit", "serve.prefill",
+            "serve.prefill.run", "serve.prefill.install",
+            "serve.prefill.sync", "serve.decode_wave",
+            "serve.decode.prepare", "serve.decode.dispatch",
+            "serve.decode.sync", "serve.decode.book"} <= names
+    n_rec = sum(1 for s in tel.trace.spans())
+    assert len(evs) == n_rec              # one profiler event per span
+
+    def inside(e, outer):
+        return any(o[0] == outer and o[1] <= e[1] and e[2] <= o[2]
+                   for o in evs)
+
+    for e in evs:
+        if e[0].startswith("serve.decode."):
+            assert inside(e, "serve.decode_wave")
+        if e[0].startswith("serve.prefill."):
+            assert inside(e, "serve.prefill")
+        if e[0] in ("serve.prefill", "serve.admit"):
+            assert inside(e, "serve.admit" if e[0] == "serve.prefill"
+                          else "serve.step")
+        if e[0] == "serve.decode_wave":
+            assert inside(e, "serve.step")
+
+
+def test_null_telemetry_leaves_no_profile_spans(lm, tmp_path):
+    cfg, model, params = lm
+    eng = ServeEngine(DecoderStepModel(model, max_len=32, prefill_chunk=8),
+                      params, slots=2)
+    _submit_mixed(eng, cfg)
+    evs = _profiled_host_events(tmp_path, eng.run)
+    assert evs                            # the session recorded the host
+    assert not [e for e in evs if e[0].startswith("serve.")]
+
+
+def test_telemetry_counts_every_compile():
+    """Backend compiles outside any jitted wrapper (an eager pad of a new
+    shape) count too, as `compiles` and a `compile` instant with the
+    program's name and seconds; close() stops the count."""
+    import jax.numpy as jnp
+    tel = Telemetry(trace=True)
+    jnp.pad(jnp.zeros((3, 1237), jnp.int32), ((0, 0), (0, 11)))
+    n = tel.registry.counters.get("compiles", 0)
+    assert n >= 1
+    inst = [e for e in tel.trace.events
+            if e["ph"] == "i" and e["name"] == "compile"]
+    assert len(inst) == n
+    assert all(e["args"]["seconds"] >= 0 and e["args"]["program"]
+               for e in inst)
+    tel.close()
+    tel.close()                           # idempotent
+    jnp.pad(jnp.zeros((3, 1239), jnp.int32), ((0, 0), (0, 13)))
+    assert tel.registry.counters["compiles"] == n
