@@ -50,13 +50,15 @@ def _check_scales(a, b, names):
 
 def paged_gqa_attention(q, pool_k, pool_v, block_tables, pos, *, length,
                         window=None, backend="xla", k_scale=None,
-                        v_scale=None):
+                        v_scale=None, active=None):
     """q: (B, H, hd); pool_k/v: (P, page, KV, hd) with H % KV == 0;
     block_tables: (B, n_chain) int32 page ids; pos: (B,) -> (B, H, hd).
 
     ``length`` is the dense cache length being emulated (ring length for
     sliding-window, where it must be <= ``window``).  ``k_scale`` /
-    ``v_scale`` (P, KV) float32 select the int8 read path."""
+    ``v_scale`` (P, KV) float32 select the int8 read path.  ``active``
+    (B,) bool lets the kernel skip inactive slots (their rows come back
+    zero); the oracle computes every row."""
     _check_backend(backend)
     _check_scales(k_scale, v_scale, "k_scale/v_scale")
     if window is not None and length > window:
@@ -68,12 +70,10 @@ def paged_gqa_attention(q, pool_k, pool_v, block_tables, pos, *, length,
                                  k_scale=k_scale, v_scale=v_scale)
     if k_scale is not None:
         return paged_gqa_fwd_q8(q, pool_k, pool_v, k_scale, v_scale,
-                                block_tables, pos, length=length,
-                                window=window,
+                                block_tables, pos, active, length=length,
                                 interpret=interpret(backend))
-    return paged_gqa_fwd(q, pool_k, pool_v, block_tables, pos,
-                         length=length, window=window,
-                         interpret=interpret(backend))
+    return paged_gqa_fwd(q, pool_k, pool_v, block_tables, pos, active,
+                         length=length, interpret=interpret(backend))
 
 
 def paged_mla_attention(q_abs, q_rope, pool_ckv, pool_krope, block_tables,
@@ -106,8 +106,12 @@ def cost_model(B, H, KV, hd, *, live_tokens, page_size, dtype_bytes=2,
     flops: 2 matmuls (q·Kᵀ, P·V) over the live tokens = 4·B·H·T·hd.
     hbm_bytes: the LIVE K/V pages streamed once (rounded up to whole
     pages — the page is the DMA granule) + q and out; block tables are
-    int32 noise.  Compare: a dense decode streams slots × max_len K/V
-    regardless of how many tokens are actually live.
+    int32 noise.  That is what the kernel's block walk copies: it stops
+    at each slot's live length.  Left out: the pool's layout puts a
+    page's KV·hd row on whole 128-lane tiles, so the copies move that
+    row rounded up to a multiple of 128 (320 -> 384 at KV 5, hd 64).
+    Compare: a dense decode streams slots × max_len K/V regardless of
+    how many tokens are actually live.
 
     A sliding-window ring holds at most ``window`` live entries — its
     page chain is bounded and recycled in place, so both terms cap

@@ -54,6 +54,15 @@ def causal_mask(q_pos, k_pos, window: int | None = None):
     return jnp.where(m, 0.0, NEG_INF)
 
 
+def _paged_write_rows(pool, page, row, x):
+    """pool (P, page, KV, hd) with x (B, KV, hd) written at entry ``row``
+    of ``page`` per slot, through the pool's (P, page, KV*hd) view."""
+    P, ps = pool.shape[:2]
+    flat = pool.reshape(P, ps, -1).at[page, row].set(
+        x.reshape(x.shape[0], -1).astype(pool.dtype))
+    return flat.reshape(pool.shape)
+
+
 def _paged_write_q8(pool, scale, wpage, in_page, fresh, tok):
     """Quantized page-granular decode write (kv_dtype="int8"): read the
     slot's live page row, grow its scale monotonically to admit the new
@@ -288,7 +297,8 @@ class GQAAttention(Module):
         the causal/window mask can see it, so paged == dense bitwise
         (bf16 pools).  "pallas" (the default) / "pallas_tpu" route the
         read through the page-indirect kernel instead (fp32 online
-        softmax; no dense view is built).  With kv_dtype="int8" the
+        softmax over each slot's live pages; inactive slots walk none
+        and read zeros; no dense view is built).  With kv_dtype="int8" the
         write quantizes into the slot's live page (``_paged_write_q8``)
         and the read dequantizes per page — in-register in the kernel,
         via the scale gather on the oracle path."""
@@ -310,17 +320,18 @@ class GQAAttention(Module):
             new = {"k": ck, "v": cv, "k_scale": cks, "v_scale": cvs}
         else:
             cks = cvs = None
-            ck = cache["k"].at[wpage, slot % ps].set(
-                k[:, 0].astype(cache["k"].dtype))
-            cv = cache["v"].at[wpage, slot % ps].set(
-                v[:, 0].astype(cache["v"].dtype))
+            # the write goes through the (P, page, KV*hd) view the
+            # kernel reads, so the pool is laid out once for both
+            ck, cv = (_paged_write_rows(cache[n], wpage, slot % ps, x[:, 0])
+                      for n, x in (("k", k), ("v", v)))
             new = {"k": ck, "v": cv}
         impl = self.cfg.paged_impl
         if impl != "gather":
             from repro.kernels.paged_attention.ops import paged_gqa_attention
             out = paged_gqa_attention(
                 q[:, 0], ck, cv, bt, pos, length=L, window=self.window,
-                backend=impl, k_scale=cks, v_scale=cvs)[:, None]
+                backend=impl, k_scale=cks, v_scale=cvs,
+                active=active)[:, None]
         else:
             if q8:
                 kd = gather_dequant(ck, cks, bt, L, q.dtype)

@@ -828,13 +828,15 @@ class ServeEngine:
         t0 = time.perf_counter()
         d0, a0 = self._n_decoded, self.n_drafts_accepted
         spec = self.drafter is not None
-        args = {}
+        args, walk = {}, None
         if tel.enabled:
             args = dict(active_slots=st.n_active, slots=self.slots,
                         ctx_tokens=int((st.pos[st.active] + 1).sum()),
                         queue_depth=st.queue_depth,
                         pages_in_use=(self.pool.pages_in_use
                                       if self.pool else 0))
+            if not spec:
+                walk = self.sm.kv_walk(st.pos, st.active)
         with tel.span("spec_wave" if spec else "decode_wave",
                       **args) as sp:
             if spec:
@@ -843,6 +845,8 @@ class ServeEngine:
             else:
                 self._plain_step()
             sp.set(tokens=self._n_decoded - d0)
+            if walk is not None:
+                sp.set(kv_blocks=walk[0], kv_blocks_grid=walk[1])
         now = time.perf_counter()
         self._rate.push(now, self._n_decoded - d0)
         if tel.enabled:

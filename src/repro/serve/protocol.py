@@ -142,6 +142,13 @@ class StepModel:
         """Optional: raw output -> recorded value (greedy debugging aid)."""
         raise NotImplementedError
 
+    def kv_walk(self, pos, active):
+        """(KV blocks the decode step's paged attention kernel walks,
+        blocks a walk of every page of every slot would take) for host
+        slot vectors ``pos`` / ``active``, or None where no layer runs
+        such a kernel."""
+        return None
+
     def write_slots(self, state, batch_state, slots):
         """Scatter a batched carry (batch axis aligned with ``slots``) into
         the slot batch.  Entries of ``slots`` >= capacity are padding and
@@ -211,6 +218,7 @@ class DecoderStepModel(StepModel):
         self.kv_layout = kv_layout
         self.paged = None
         self._pool_names = frozenset()
+        self._gqa_walks = {}
         if kv_layout == "paged":
             from repro.serve.paged import PagedConfig
             if not self.positional:
@@ -247,6 +255,7 @@ class DecoderStepModel(StepModel):
             self._ring_lens = sorted(ring)
             self._has_global = has_global
             self._has_window = bool(ring)
+            self._gqa_walks = self._gqa_kernel_walks()
         # in the model's native cache layout, scanned-unit leaves carry the
         # layer-repeat axis FIRST — their slot (batch) axis is 1, not 0.
         self._slot_axis = {name: (1 if mode == "scanned" else 0)
@@ -319,6 +328,44 @@ class DecoderStepModel(StepModel):
         for L in self._ring_lens:
             out.add((int(pos) % L) // ps)
         return sorted(out)
+
+    def _gqa_kernel_walks(self):
+        """{(ring length, pages per block): layers} over the layers whose
+        decode read is the paged GQA kernel (``paged_impl`` not
+        "gather"); scanned units count once per repeat."""
+        from repro.kernels.paged_attention.paged_attention import (
+            pages_per_block)
+        from repro.models.attention import GQAAttention
+        walks = {}
+        if self.model.cfg.paged_impl == "gather":
+            return walks
+        ps = self.paged.page_size
+        for name, lyr, mode in self.model._all_layers():
+            if (name not in self._pool_names
+                    or not isinstance(lyr.mixer, GQAAttention)):
+                continue
+            L = lyr.mixer.ring_length(self.max_len)
+            pool = lyr.mixer.paged_cache_spec(1, ps)["k"]
+            key = (L, pages_per_block(ps, pool.shape[2], pool.shape[3],
+                                      pool.dtype, -(-L // ps)))
+            n = self.model.cfg.n_repeats if mode == "scanned" else 1
+            walks[key] = walks.get(key, 0) + n
+        return walks
+
+    def kv_walk(self, pos, active):
+        """Blocks walked and blocks of a full walk, summed over the
+        decode step's paged GQA kernel calls (one per layer), from the
+        same ``pages_per_block`` the kernel uses."""
+        if not self._gqa_walks:
+            return None
+        from repro.kernels.paged_attention.paged_attention import (
+            blocks_walked)
+        walked = grid = 0
+        for (L, ppb), n in self._gqa_walks.items():
+            w, g = blocks_walked(pos, active, length=L,
+                                 page_size=self.paged.page_size, ppb=ppb)
+            walked, grid = walked + n * w, grid + n * g
+        return walked, grid
 
     def check_prefix_cacheable(self):
         """Prefix caching reconstructs a request's WHOLE decode state

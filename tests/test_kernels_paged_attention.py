@@ -20,6 +20,7 @@ import numpy as np
 import pytest
 
 from repro.kernels.paged_attention import ops, quant, ref
+from repro.kernels.paged_attention import paged_attention as pa
 
 # pallas-vs-gather max |err| bound, per attention family (see module
 # docstring; README "Paged KV cache" documents the same numbers)
@@ -406,3 +407,124 @@ def test_cost_model_mla_variant():
                                      page_size=ps, dtype_bytes=1,
                                      scale_bytes=4)
     assert q8_b < nbytes
+
+
+# ---------------------------------------------------------------------------
+# the GQA block walk: chains of several blocks, walks that end at each
+# slot's live length, inactive slots, wrapped rings, int8 pools
+# ---------------------------------------------------------------------------
+
+# 1040 tokens of 16-token pages: 65 pages, walked in blocks of 32 pages
+# (512 tokens), so a full walk is two whole blocks and a partial one
+WALK_L, WALK_PS, WALK_KV, WALK_HD = 1040, 16, 2, 16
+
+
+def _walk_pools(rng, L, *, B=4):
+    q, _dk, _dv, pk, pv, bt, _pos = _setup_gqa(
+        rng, B=B, H=4, KV=WALK_KV, hd=WALK_HD, L=L, ps=WALK_PS,
+        num_pages=B * (-(-L // WALK_PS)) + 8)
+    return q, pk, pv, bt
+
+
+def test_walk_blocks_are_several_and_ragged():
+    """The shapes below do walk several blocks, the last one partial."""
+    n_pages = -(-WALK_L // WALK_PS)
+    ppb = pa.pages_per_block(WALK_PS, WALK_KV, WALK_HD, jnp.float32,
+                             n_pages)
+    assert (ppb, n_pages % ppb) == (32, 1)
+    # a chain shorter than one block is one block of the whole chain
+    assert pa.pages_per_block(WALK_PS, WALK_KV, WALK_HD, jnp.float32,
+                              5) == 5
+    # int8 pages take half the VMEM of bf16 ones, never more tokens
+    assert pa.pages_per_block(16, 5, 64, jnp.int8, 128) == 32
+    # wide pages are cut to the VMEM budget
+    assert pa.pages_per_block(16, 8, 128, jnp.float32, 128) == 16
+
+
+BT = 512      # tokens of one block at the walk shapes
+
+WALK_CASES = {
+    # name: (pos, active, window, int8)
+    "ragged": ([37, 1000, 530, 1039], None, None, False),
+    "block_edges": ([BT - 1, BT, 0, 2 * BT - 1], None, None, False),
+    "one_token_and_full_length": ([0, WALK_L - 1, 0, WALK_L - 1], None,
+                                  None, False),
+    "inactive_slot": ([700, WALK_L - 1, 3, 900], [True, False, True, False],
+                      None, False),
+    # ring of 600 entries (38 pages: a block and a partial one), past
+    # its wrap in three slots and just filled in one
+    "ring_wrapped": ([700, 1500, 599, 100], None, 600, False),
+    "int8": ([BT - 1, BT, 0, WALK_L - 1], [True, True, False, True], None,
+             True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WALK_CASES))
+def test_block_walk_matches_oracle(case):
+    pos, active, window, q8 = WALK_CASES[case]
+    fam = "gqa_global" if window is None else "gqa_window"
+    rng = np.random.default_rng(12)
+    L = window or WALK_L
+    q, pk, pv, bt = _walk_pools(rng, L)
+    pos = np.asarray(pos, np.int32)
+    kw = dict(length=L, window=window)
+    pools = (jnp.asarray(pk), jnp.asarray(pv))
+    if q8:
+        ks = quant.page_abs_scale(pools[0])
+        vs = quant.page_abs_scale(pools[1])
+        pools = (quant.quantize(pools[0], ks), quant.quantize(pools[1], vs))
+        kw.update(k_scale=ks, v_scale=vs)
+    args = (jnp.asarray(q),) + pools + (jnp.asarray(bt), jnp.asarray(pos))
+    want = np.asarray(ops.paged_gqa_attention(*args, backend="xla", **kw))
+    act = None if active is None else jnp.asarray(active)
+    got = np.asarray(ops.paged_gqa_attention(*args, backend="pallas",
+                                             active=act, **kw))
+    live = np.ones(len(pos), bool) if active is None else np.asarray(active)
+    err = float(np.max(np.abs(got[live] - want[live])))
+    assert err <= PALLAS_TOL[fam], f"{case}: |err|={err:.3e}"
+    # an inactive slot walks nothing and reads zeros
+    assert not np.any(got[~live])
+
+
+@pytest.mark.parametrize("q8", [False, True])
+def test_dead_blocks_are_never_read(q8):
+    """Every page past each slot's live length — and every block-table
+    entry past it — holds NaN (int8: NaN scales).  Had the walk copied
+    one into the math, its NaN values would reach the output through
+    P·V, whatever the score mask did; so active slots' outputs must stay
+    bit-for-bit those of clean pools."""
+    rng = np.random.default_rng(13)
+    q, pk, pv, bt = _walk_pools(rng, WALK_L)
+    pos = np.array([0, BT - 1, WALK_L - 1, 700], np.int32)
+    active = np.array([True, True, True, False])
+    live_pages = np.where(active, -(-(pos + 1) // WALK_PS), 0)
+    P = pk.shape[0]
+    dead = np.zeros(P, bool)
+    bt_nan = np.array(bt)
+    spare = np.setdiff1d(np.arange(P), bt)       # pages no chain owns
+    for b in range(len(pos)):
+        dead[bt[b, live_pages[b]:]] = True
+        bt_nan[b, live_pages[b]:] = spare[b % len(spare)]
+    dead[spare] = True
+    runs = []
+    for poisoned in (False, True):
+        k, v = jnp.asarray(pk), jnp.asarray(pv)
+        kw = {}
+        if q8:
+            ks, vs = quant.page_abs_scale(k), quant.page_abs_scale(v)
+            k, v = quant.quantize(k, ks), quant.quantize(v, vs)
+            if poisoned:
+                ks = jnp.where(dead[:, None], jnp.nan, ks)
+                vs = jnp.where(dead[:, None], jnp.nan, vs)
+            kw = dict(k_scale=ks, v_scale=vs)
+        elif poisoned:
+            k = jnp.where(dead[:, None, None, None], jnp.nan, k)
+            v = jnp.where(dead[:, None, None, None], jnp.nan, v)
+        table = bt_nan if poisoned else bt
+        runs.append(np.asarray(ops.paged_gqa_attention(
+            jnp.asarray(q), k, v, jnp.asarray(table), jnp.asarray(pos),
+            length=WALK_L, backend="pallas", active=jnp.asarray(active),
+            **kw)))
+    clean, poisoned = runs
+    assert np.all(np.isfinite(clean))
+    np.testing.assert_array_equal(poisoned[active], clean[active])

@@ -600,3 +600,55 @@ def test_telemetry_counts_every_compile():
     tel.close()                           # idempotent
     jnp.pad(jnp.zeros((3, 1239), jnp.int32), ((0, 0), (0, 13)))
     assert tel.registry.counters["compiles"] == n
+
+
+def test_decode_wave_kv_walk_counter(monkeypatch):
+    """`decode_wave` ends with `kv_blocks` (the blocks the paged GQA
+    kernel walks that wave) and `kv_blocks_grid` (the blocks a walk of
+    every page of every slot would take), summed over the layers that
+    run the kernel; with telemetry off nothing is counted."""
+    import jax.numpy as jnp
+
+    from repro.kernels.paged_attention.paged_attention import (
+        blocks_walked, pages_per_block)
+    cfg = dataclasses.replace(get_config("smollm-360m-smoke"),
+                              paged_impl="pallas")
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    # 1040 tokens of 16-token pages: 65 pages in blocks of 32, three
+    # blocks for a full walk of one slot
+    mk = lambda: DecoderStepModel(model, max_len=1040,  # noqa: E731
+                                  prefill_chunk=64, kv_layout="paged",
+                                  paged=PagedConfig(page_size=16))
+    sm = mk()
+    assert pages_per_block(16, cfg.n_kv_heads, cfg.head_dim, jnp.bfloat16,
+                           65) == 32
+    layers = cfg.n_layers
+    # live tokens 1, 512, 513, 1040 and an inactive slot: 1+1+2+3 blocks
+    pos = np.array([0, 511, 512, 1039, 700])
+    active = np.array([True, True, True, True, False])
+    assert blocks_walked(pos, active, length=1040, page_size=16,
+                         ppb=32) == (7, 15)
+    assert sm.kv_walk(pos, active) == (7 * layers, 15 * layers)
+
+    tel = Telemetry(trace=True)
+    eng = ServeEngine(sm, params, slots=2, telemetry=tel)
+    rng = np.random.default_rng(5)
+    for n in (5, 520):
+        eng.submit(rng.integers(0, cfg.vocab, size=n), max_new_tokens=3)
+    eng.run()
+    tel.close()
+    waves = [s.args for s in tel.trace.spans() if s.name == "decode_wave"]
+    # both slots decode from positions 5 and 520 on: live 6 and 521
+    # tokens, then 7 and 522 — one block and two each wave
+    assert [(a["ctx_tokens"], a["kv_blocks"], a["kv_blocks_grid"])
+            for a in waves] == [(6 + 521, 3 * layers, 6 * layers),
+                                (7 + 522, 3 * layers, 6 * layers)]
+
+    def no_count(*_a):
+        raise AssertionError("kv_walk called with telemetry off")
+    quiet = mk()
+    monkeypatch.setattr(quiet, "kv_walk", no_count)
+    eng = ServeEngine(quiet, params, slots=2)
+    eng.submit(rng.integers(0, cfg.vocab, size=5), max_new_tokens=3)
+    assert len(eng.run()) == 1

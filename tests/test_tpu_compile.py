@@ -58,7 +58,7 @@ def _compiled_kernels(chip, fn, *shapes):
 def test_paged_gqa_compiles(chip, window):
     length = window or L
     fn = lambda q, k, v, bt, pos: paged_gqa_fwd(  # noqa: E731
-        q, k, v, bt, pos, length=length, window=window, interpret=False)
+        q, k, v, bt, pos, length=length, interpret=False)
     assert "paged_gqa_decode" in _compiled_kernels(
         chip, fn, ((B, H, HD), BF16), ((P, PS, KV, HD), BF16),
         ((P, PS, KV, HD), BF16), ((B, L // PS), I32), ((B,), I32))
