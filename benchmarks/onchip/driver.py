@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import gc
 import math
 import sys
@@ -46,16 +47,35 @@ def log(msg):
 # ----------------------------------------------------------------------
 # the system under test
 # ----------------------------------------------------------------------
+def _setting(cfg, key, value):
+    """A value of the file's ``model``: a dict replaces fields of the
+    sub-config ``key`` (``moe``, ``mamba``, ``mla``), anything else is the
+    field's value."""
+    if not isinstance(value, dict):
+        return value
+    sub = getattr(cfg, key)
+    if not dataclasses.is_dataclass(sub):
+        raise ValueError(f"{cfg.name} has no {key} settings to replace "
+                         f"fields of (it is {sub!r})")
+    return dataclasses.replace(sub, **value)
+
+
 def build_model(config: dict):
     """The configuration as it runs, checked against the sizes its file
-    states."""
+    states; a dotted size (``"moe.n_experts"``) names a field of a
+    sub-config."""
     from repro.configs import get_config
     from repro.models import build_model as program_build_model
-    cfg = dataclasses.replace(get_config(config["arch"]), **config["model"])
+    base = get_config(config["arch"])
+    cfg = dataclasses.replace(base, **{k: _setting(base, k, v)
+                                       for k, v in config["model"].items()})
     for k, v in config["sizes"].items():
-        if k != "mixer" and getattr(cfg, k) != v:
+        if k == "mixer":
+            continue
+        got = functools.reduce(getattr, k.split("."), cfg)
+        if got != v:
             raise ValueError(f"{config['name']}: the program's {k} is "
-                             f"{getattr(cfg, k)!r}, the file says {v!r}")
+                             f"{got!r}, the file says {v!r}")
     return program_build_model(cfg)
 
 
@@ -382,7 +402,8 @@ def layer_context(cell, win, probe, recs, device_kind, sizes):
         prefills=[p for p in probe.prefills if inside(p[0])],
         requests=recs, gaps=win.gaps, pool=win.pool, sizes=sizes,
         config=cell.config, traffic=cell.traffic,
-        peaks=spec_mod.peaks(device_kind), cost=spec_mod.kernel_cost)
+        peaks=spec_mod.peaks(device_kind), cost=spec_mod.kernel_cost,
+        reference=spec_mod.reference)
 
 
 def run_cell(cell, seed, seconds, trace, *, t_start, devices,
@@ -400,7 +421,9 @@ def run_cell(cell, seed, seconds, trace, *, t_start, devices,
     rate = cell.cell.get("rate_per_s")
     sched = traffic_mod.schedule(traffic, seed, seconds, sizes["vocab"],
                                  rate)
-    params = weights_mod.make(model, seed)
+    ref = spec_mod.reference(config["name"])
+    params = weights_mod.make(model, seed, getattr(ref, "INIT", None),
+                              where=f"INIT of {ref.__file__}")
     phases["weights_s"] = clock() - tp
     tp = clock()
     eng = build_engine(model, params, config)

@@ -1,5 +1,7 @@
 """Model FLOPs per token from the configuration's sizes (what the
-algorithm needs; recomputation and padding are not counted).
+algorithm needs; recomputation and padding are not counted), for the
+``attn`` and ``mingru`` mixers.  A configuration of another kind counts
+its own in its ``references/<config>.py`` (``reduce.prefill_flops``).
 
 A matrix product of an m-by-k by k-by-n costs 2mkn.  Per token and layer:
 attention projections 2d(H + 2KV)hd + 2 H hd d, the SwiGLU MLP 6 d f,

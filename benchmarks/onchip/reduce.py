@@ -4,9 +4,11 @@ A reader gets one context dict from the traced run (``driver.
 layer_context``): the reduced device trace (``events``), the host-clock
 windows, the decode steps and prefills the layer probes recorded inside
 the traced window, the request records, inter-token gaps, page-pool
-samples, the configuration's sizes, the chip's peaks and the loader of
-kernel cost functions.  A reader returns a number, or None where it
-finds nothing to read; it never returns 0 for a share of a peak.
+samples, the configuration's sizes, the chip's peaks, the loader of
+kernel cost functions (``cost``) and the loader of a configuration's
+reference module (``reference``), which may hold its own FLOP counts.
+A reader returns a number, or None where it finds nothing to read; it
+never returns 0 for a share of a peak.
 """
 from __future__ import annotations
 
@@ -59,16 +61,29 @@ def roofline_share(ctx, kernel, calls):
     return 100.0 * least / k_s
 
 
+def _own(ctx, fn):
+    """The configuration's own FLOP function ``fn`` from its
+    ``references/<config>.py``, or None where it defines none."""
+    return getattr(ctx["reference"](ctx["config"]["name"]), fn, None)
+
+
 def decode_flops(ctx):
+    """Model FLOPs of the decode steps: the configuration's
+    ``decode_flops(sizes, n_active, ctx_tokens)``, or flops.py by mixer."""
     s = ctx["sizes"]
-    return sum(flops_mod.decode_step(s, s["mixer"], n, c)
-               for _t, n, c in ctx["steps"])
+    own = _own(ctx, "decode_flops")
+    step = own or (lambda s, n, c: flops_mod.decode_step(s, s["mixer"], n, c))
+    return sum(step(s, n, c) for _t, n, c in ctx["steps"])
 
 
 def prefill_flops(ctx):
+    """Model FLOPs of the prefilled prompts: the configuration's
+    ``prefill_flops(sizes, prompt_len)`` per prompt, or flops.py by
+    mixer."""
     s = ctx["sizes"]
-    return sum(rows * flops_mod.prefill(s, s["mixer"], p)
-               for _t, rows, p in ctx["prefills"])
+    own = _own(ctx, "prefill_flops")
+    one = own or (lambda s, p: flops_mod.prefill(s, s["mixer"], p))
+    return sum(rows * one(s, p) for _t, rows, p in ctx["prefills"])
 
 
 def mfu(ctx, model_flops, programs):

@@ -12,6 +12,8 @@ step below the bfloat16 the configurations compute in.
 """
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 
@@ -52,25 +54,72 @@ def swiglu(x, p, quant=None):
     return dot(jax.nn.silu(g) * u, p["w_down"], quant)
 
 
-def stack(params, block, tokens, sizes, quant=None):
-    """Embedding, the scanned layer stack (``block(x, layer_params)``
-    returns the layer's output) and the final norm: (S,) ids -> (S, d)."""
+_LAYER = re.compile(r"(head|unit|tail)(\d+)(?:_r(\d+))?")
+
+
+def _layers(params):
+    """The program's layer names by kind of place: ``head``, ``unit``
+    (the pattern, its leaves stacked over the repeats), ``repeat`` (the
+    pattern of a model with one repeat, which the program does not stack:
+    ``unit<i>_r<r>``) and ``tail``, each in the order the layers run."""
+    out = {"head": [], "unit": [], "repeat": [], "tail": []}
+    for k in params:
+        m = _LAYER.fullmatch(k)
+        if m:
+            place, i, r = m.group(1), int(m.group(2)), m.group(3)
+            place = "repeat" if r is not None else place
+            out[place].append(((int(r or 0), i), k))
+    return {p: [k for _o, k in sorted(v)] for p, v in out.items()}
+
+
+def stack(params, block, tokens, sizes, quant=None, kinds=None):
+    """Embedding, the layers in the program's order and the final norm:
+    (S,) ids -> (S, d).
+
+    The program's layers are ``head0, head1, ..``, then the pattern
+    ``unit0 .. unitK-1`` once for each repeat, then ``tail0, tail1, ..``.
+    ``block(x, layer_params)`` returns a layer's output.  Where layers
+    differ in kind, ``block`` is a dict of such functions by kind and
+    ``kinds`` gives each layer's kind by its name (``{"unit4": "attn"}``;
+    a pattern layer by its ``unit<i>`` name at every repeat).
+    """
     x = fake_quant(params["embed"]["table"][tokens].astype(jnp.float32),
                    quant, -1)
-    layers = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32),
-                                    params["unit0"])
+    names = _layers(params)
 
-    def body(x, p):
-        return fake_quant(block(x, p), quant, -1), None
+    def f32(tree):
+        return jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), tree)
 
-    x, _ = jax.lax.scan(body, x, layers)
+    def layer(name, x, p):
+        fn = block if kinds is None else block[kinds[name.split("_r")[0]]]
+        return fake_quant(fn(x, p), quant, -1)
+
+    for name in names["head"] + names["repeat"]:
+        x = layer(name, x, f32(params[name]))
+    if names["unit"]:
+        def body(x, ps):
+            for name in names["unit"]:
+                x = layer(name, x, ps[name])
+            return x, None
+
+        x, _ = jax.lax.scan(body, x,
+                            {n: f32(params[n]) for n in names["unit"]})
+    for name in names["tail"]:
+        x = layer(name, x, f32(params[name]))
     return rmsnorm(x, params["final_norm"]["scale"], sizes["norm_eps"])
 
 
-def head(params, x, rows, sizes, quant=None):
-    """Tied-embedding logits of the rows ``rows`` of x, scaled by
-    1/sqrt(d_model) as the configuration states: -> (len(rows), vocab)."""
-    table = params["embed"]["table"].astype(jnp.float32)
+def head(params, x, rows, sizes, quant=None, table="embed", scale=None):
+    """Logits of the rows ``rows`` of x against the output table
+    ``params[table]["table"]`` (the tied embedding; ``"lm_head"`` for an
+    untied one), over sqrt(d_model) as the configurations state, or times
+    ``scale`` where a configuration states its own: -> (len(rows), vocab).
+    """
+    out = params[table]["table"].astype(jnp.float32)
     h = x[rows]
-    logits = dot(h, table.T, quant) / jnp.sqrt(float(sizes["d_model"]))
+    logits = dot(h, out.T, quant)
+    if scale is None:
+        logits = logits / jnp.sqrt(float(sizes["d_model"]))
+    else:
+        logits = logits * scale
     return logits[:, :sizes["vocab"]]
